@@ -1,51 +1,39 @@
-"""jax 0.4 / 0.6 API compatibility shims, defined once.
+"""The JAX mesh and manual-SPMD entry points the repo uses, defined once.
 
-The manual-SPMD modules (``core/pipeline.py``, ``core/expert.py``) and the
-GSPMD plumbing (``core/parallel.py``) each need entry points that jax
-renamed between 0.4.x and 0.6:
-
-  * ``shard_map``  — moved from ``jax.experimental.shard_map`` to
-    ``jax.shard_map``, and the replication-check kwarg was renamed
-    ``check_rep`` -> ``check_vma``.  All repo shard_maps are fully manual
-    (ppermute / all_to_all schedules) and disable the check.
-  * ``use_mesh``   — the ambient-mesh context manager moved from "the Mesh
-    object is the context manager" to ``jax.sharding.use_mesh`` to
-    ``jax.set_mesh``.
-
-Keeping the shims here (instead of copy-pasted per module) means a jax
-upgrade touches exactly one file.
+  * ``make_mesh`` / ``abstract_mesh`` — every mesh the repo builds comes
+    from here, with ``AxisType.Auto`` on every axis.  ``jax.make_mesh``
+    defaults to *Explicit* axes, under which ``with_sharding_constraint``
+    acts as an assertion and gathers whose output sharding is ambiguous
+    (the embedding lookup) raise ``ShardingTypeError``; the repo's plans
+    are GSPMD hints, which is what Auto axes mean.
+  * ``shard_map`` — all repo shard_maps are fully manual (ppermute /
+    all_to_all schedules) and disable the replication check.
+  * ``use_mesh`` — the ambient-mesh context manager for jit/constraints.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-if hasattr(jax, "shard_map"):          # jax >= 0.6
-    def shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+from jax.sharding import AbstractMesh, AxisType
 
 
-def use_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh for jit/constraints.
+def _auto(axes):
+    return (AxisType.Auto,) * len(axes)
 
-    jax renamed this entry point across releases (``jax.set_mesh`` /
-    ``jax.sharding.use_mesh``); on older versions the Mesh object itself is
-    the context manager.  All repo code goes through this helper.
-    """
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    fn = getattr(jax.sharding, "use_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
+
+def make_mesh(shape, axes, devices=None):
+    """Device mesh of ``shape`` over ``axes``, every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes), _auto(axes),
+                         devices=devices)
+
+
+def abstract_mesh(shape, axes):
+    """Device-free mesh for sharding analysis, every axis Auto."""
+    return AbstractMesh(tuple(shape), tuple(axes), _auto(axes))
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+use_mesh = jax.set_mesh

@@ -504,8 +504,38 @@ def make_runtime(cfg: ModelConfig, plan: ParallelPlan, shape: ShapeConfig,
     if per_block and plan.fsdp:
         kw["gather_params"] = make_param_gatherer(cfg, plan)
         kw["gather_prefetch"] = plan.zero_overlap
+    if plan.mesh.size > 1:
+        kw["kernel_shard"] = make_kernel_sharder(plan)
     kw.update(overrides)
     return Runtime(**kw)
+
+
+def make_kernel_sharder(plan: ParallelPlan):
+    """Run a Pallas kernel on each device's shard of its operands.
+
+    GSPMD cannot partition a Mosaic (Pallas TPU) kernel, so under a
+    multi-device plan every kernel call sits in a shard_map over the plan's
+    mesh.  The batch dim (dim 0) shards over the plan's data axes.  For
+    attention (``heads=True``) dim 2 also shards over the model axis when
+    both query and kv heads are model-sharded, which keeps each GQA group
+    on one device.  Every other dim is whole inside the kernel, so
+    sequence- or head-sharded operands are gathered at its boundary.
+    Operands of another rank than the first (rmsnorm's scale, wkv6's u)
+    are replicated.
+    """
+    from repro.core.compat import shard_map
+    head_axis = plan.tp if plan.attn == "head_tp" and plan.kv_tp else None
+
+    def shard(fn, *args, heads=False):
+        x = args[0]
+        entries = [plan.dp] + [None] * (x.ndim - 1)
+        if heads and head_axis:
+            entries[2] = head_axis
+        spec = _fit_spec(P(*entries), x.shape, plan.mesh)
+        in_specs = tuple(spec if a.ndim == x.ndim else P() for a in args)
+        return shard_map(fn, plan.mesh, in_specs, spec)(*args)
+
+    return shard
 
 
 def make_constrainer(cfg: ModelConfig, plan: ParallelPlan):

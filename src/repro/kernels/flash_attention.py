@@ -17,6 +17,10 @@ TPU-native design (not a CUDA port, see DESIGN.md §2):
   * BlockSpecs tile q/k/v to (block_q|block_kv, head_dim) VMEM windows;
     block sizes default to 128/256 to keep the MXU's 128-lane shape and a
     working set of ~(2*bq*D + 2*bk*D + bq*bk)*4B well under VMEM.
+  * the per-row residuals lse and delta are stored as (N, 1, Sp) rows with
+    (1, 1, bq) blocks: the TPU tiles the last two block dims to (8, 128),
+    which a (1, bq) block over an (N, Sp) array violates.  The kernels
+    transpose the row to the (bq, 1) column the softmax math needs.
   * GQA: q heads are grouped by kv head via index_map arithmetic — no
     repeated K/V in HBM.
   * causal + sliding-window masks built from absolute block offsets with
@@ -92,7 +96,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(denom))[:, 0]
+        lse_ref[0] = (m_scr[...] + jnp.log(denom)).T        # (1, bq)
 
 
 # ---------------------------------------------------------------------------
@@ -114,16 +118,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)                    # (bk, D)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)                  # (bq, D)
-    lse = lse_ref[0].astype(jnp.float32)                # (bq,)
-    delta = delta_ref[0].astype(jnp.float32)            # (bq,)
+    lse = lse_ref[0].T                                  # (bq, 1)
+    delta = delta_ref[0].T                              # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     mask = _block_mask(qi, kj, block_q, block_kv, causal, window, seq_len)
     # recompute probabilities from the saved logsumexp; masked entries are
     # zeroed explicitly so padded/fully-masked rows (lse == NEG_INF) vanish
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)            # (bq, bk)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)        # (bq, bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))      # (bq, bk)
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     dq_scr[...] += jax.lax.dot(ds, k) * scale
 
     @pl.when(kj == n_kv - 1)
@@ -150,15 +154,15 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)                    # (bk, D)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)                  # (bq, D)
-    lse = lse_ref[0].astype(jnp.float32)                # (bq,)
-    delta = delta_ref[0].astype(jnp.float32)            # (bq,)
+    lse = lse_ref[0].T                                  # (bq, 1)
+    delta = delta_ref[0].T                              # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     mask = _block_mask(qi, kj, block_q, block_kv, causal, window, seq_len)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)            # (bq, bk)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)        # (bq, bk)
     dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ()))) * scale
 
     @pl.when(t == n_t - 1)
@@ -233,11 +237,11 @@ def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Kv * G, Sp, D), q.dtype),
-            jax.ShapeDtypeStruct((B * Kv * G, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((B * Kv * G, 1, Sp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -245,6 +249,7 @@ def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret):
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qg, kg, vg)
 
     # residuals keep the grouped/padded layouts: the backward reuses them
@@ -262,7 +267,8 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
     dog = _group_q(g, Kv, G, Sp)
     # delta_i = sum_d do_i * o_i — the rowwise correction term of dsoftmax;
     # O(S*D) elementwise, cheaper as one fused jnp reduce than a kernel pass
-    delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32),
+                    axis=-1)[:, None, :]                # (N, 1, Sp) like lse
 
     scale = D ** -0.5
     dq_kernel = functools.partial(
@@ -276,13 +282,14 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
             pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Kv * G, Sp, D), qg.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qg, kg, vg, dog, lse, delta)
 
     n_t = G * nq
@@ -300,10 +307,10 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
             pl.BlockSpec((1, bq, D),
                          lambda b, j, t, G=G, nq=nq: (b * G + t // nq, t % nq, 0)),
-            pl.BlockSpec((1, bq),
-                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, t % nq)),
-            pl.BlockSpec((1, bq),
-                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, t % nq)),
+            pl.BlockSpec((1, 1, bq),
+                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, 0, t % nq)),
+            pl.BlockSpec((1, 1, bq),
+                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, 0, t % nq)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
@@ -316,6 +323,7 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qg, kg, vg, dog, lse, delta)
 
     dq = _ungroup_q(dq, B, Kv, G, S)

@@ -14,6 +14,11 @@ context — so the kernel layout follows flash-decode rather than FA2:
     index maps read ``tbl[b, s * bps + j]`` to DMA exactly the pool block
     this grid cell needs — the gather lives in the index map, the kernel
     body never sees a pool-sized tensor.
+  * the pools are laid out (P, Kv, bs, D), so one grid cell's K/V block
+    (1, 1, bs, D) is a whole (bs, D) slab: the TPU tiles the last two block
+    dims to (8, 128), and slicing one kv head out of a (bs, Kv, D) block
+    would cut the tiled Kv dim.  For the same reason the per-split m and l
+    leave as (G, 1) columns, not as a slice of the splits axis.
   * each split writes its *partial* (acc, m, l); the host-side wrapper
     merges splits with one logsumexp combine (empty splits carry
     m = -inf, l = 0 and vanish).  GQA comes for free: the G query heads
@@ -47,8 +52,8 @@ def _decode_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)                 # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bs, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                 # (bs, D)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, bs)
 
@@ -74,14 +79,14 @@ def _decode_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref,
         # partial (unnormalized) outputs: the wrapper's logsumexp combine
         # across splits does the single global normalization
         o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-        m_ref[0, 0] = m_scr[..., 0]
-        l_ref[0, 0] = l_scr[..., 0]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("n_splits", "interpret"))
 def flash_decode(q, k_pool, v_pool, tbl, ctx, *, n_splits=4,
                  interpret=False):
-    """q (B, 1, H, D), pools (P, bs, Kv, D), tbl (B, max_blocks) int32,
+    """q (B, 1, H, D), pools (P, Kv, bs, D), tbl (B, max_blocks) int32,
     ctx (B,) int32 -> (B, 1, H, D).
 
     tbl entries < 0 (unallocated) are clamped for the gather; their
@@ -89,7 +94,7 @@ def flash_decode(q, k_pool, v_pool, tbl, ctx, *, n_splits=4,
     attention only — the jnp paged path handles sliding windows.
     """
     B, Sq, H, D = q.shape
-    P, bs, Kv, _ = k_pool.shape
+    P, Kv, bs, _ = k_pool.shape
     assert Sq == 1 and H % Kv == 0, (q.shape, Kv)
     G = H // Kv
     nb = tbl.shape[1]
@@ -115,17 +120,17 @@ def flash_decode(q, k_pool, v_pool, tbl, ctx, *, n_splits=4,
             pl.BlockSpec((1, 1, G, D),
                          lambda b, s, j, tbl, ctx, Kv=Kv: (b // Kv, b % Kv,
                                                            0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
+            pl.BlockSpec((1, 1, bs, D),
                          lambda b, s, j, tbl, ctx, Kv=Kv, bps=bps:
-                         (tbl[b // Kv, s * bps + j], 0, b % Kv, 0)),
-            pl.BlockSpec((1, bs, 1, D),
+                         (tbl[b // Kv, s * bps + j], b % Kv, 0, 0)),
+            pl.BlockSpec((1, 1, bs, D),
                          lambda b, s, j, tbl, ctx, Kv=Kv, bps=bps:
-                         (tbl[b // Kv, s * bps + j], 0, b % Kv, 0)),
+                         (tbl[b // Kv, s * bps + j], b % Kv, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, s, j, tbl, ctx: (b, s, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, s, j, tbl, ctx: (b, s, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, s, j, tbl, ctx: (b, s, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, s, j, tbl, ctx: (b, s, 0, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, s, j, tbl, ctx: (b, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
@@ -138,13 +143,15 @@ def flash_decode(q, k_pool, v_pool, tbl, ctx, *, n_splits=4,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B * Kv, splits, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B * Kv, splits, G), jnp.float32),
-            jax.ShapeDtypeStruct((B * Kv, splits, G), jnp.float32),
+            jax.ShapeDtypeStruct((B * Kv, splits, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * Kv, splits, G, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(safe_tbl, ctx, qg, k_pool, v_pool)
 
     # logsumexp merge across splits: empty splits (m=-inf, l=0) vanish
+    m, l = m[..., 0], l[..., 0]                          # (B*Kv, S, G)
     m_max = jnp.max(m, axis=1, keepdims=True)            # (B*Kv, 1, G)
     alpha = jnp.exp(m - m_max)                           # (B*Kv, S, G)
     l_tot = jnp.sum(l * alpha, axis=1)                   # (B*Kv, G)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 ``interpret='auto'`` executes the kernel bodies in Python on CPU (the
-validation substrate) and compiles them for real on TPU; the backend probe
-is memoized at module level so the hot path never re-queries XLA.  Model
+validation substrate) and compiles them for real on TPU; on a TPU no
+argument can select interpret mode.  The backend probe is memoized at
+module level so the hot path never re-queries XLA.  Model
 code calls these through ``Runtime.attn_impl == 'pallas'`` /
 ``Runtime.norm_impl == 'pallas'`` — both forward and backward run as Pallas
 kernels (``custom_vjp``), so ``jax.grad`` through a train step stays on the
@@ -21,12 +22,14 @@ _IS_TPU = None      # memoized jax.default_backend() == 'tpu' probe
 
 
 def _interp(interpret):
-    if interpret == "auto":
-        global _IS_TPU
-        if _IS_TPU is None:
-            _IS_TPU = jax.default_backend() == "tpu"
-        return not _IS_TPU
-    return bool(interpret)
+    global _IS_TPU
+    if _IS_TPU is None:
+        _IS_TPU = jax.default_backend() == "tpu"
+    if _IS_TPU:
+        if interpret != "auto" and interpret:
+            raise ValueError("Pallas interpret mode requested on a TPU")
+        return False
+    return True if interpret == "auto" else bool(interpret)
 
 
 def _dtype_blocks(dtype, f32_val: int) -> int:
@@ -34,8 +37,7 @@ def _dtype_blocks(dtype, f32_val: int) -> int:
 
     TPU tiling is (8, 128) sublanes x lanes at f32 but (16, 128) at bf16
     — half the bytes per element means a 2x-larger block fills the same
-    VMEM footprint while halving grid/loop overhead, which is where the
-    bf16 kernels were leaving throughput (BENCH_kernels.json).
+    VMEM footprint with half the grid steps.  Not yet tuned on a chip.
     """
     import jax.numpy as jnp
     return f32_val * (2 if jnp.dtype(dtype).itemsize <= 2 else 1)
@@ -54,7 +56,7 @@ def attention(q, k, v, *, causal=True, window=0, block_q=None, block_kv=None,
 def paged_decode_attention(q, k_pool, v_pool, tbl, ctx, *, n_splits=4,
                            interpret="auto"):
     """Flash-decode over a paged KV cache (forward-only; decode has no
-    backward).  q (B,1,H,D); pools (P,bs,Kv,D); tbl (B,max_blocks) int32;
+    backward).  q (B,1,H,D); pools (P,Kv,bs,D); tbl (B,max_blocks) int32;
     ctx (B,) int32 valid positions per request."""
     return _flash_decode(q, k_pool, v_pool, tbl, ctx, n_splits=n_splits,
                          interpret=_interp(interpret))

@@ -36,18 +36,21 @@ def paged_attention_ref(q, k_pool, v_pool, tbl, ctx, *, window=0):
     """Decode attention over a paged KV cache (fp32 softmax oracle).
 
     q (B, 1, H, D) one query token per request; k_pool/v_pool
-    (P, bs, Kv, D) shared block pools; tbl (B, max_blocks) int32 block
+    (P, Kv, bs, D) shared block pools; tbl (B, max_blocks) int32 block
     table (-1 = unallocated); ctx (B,) int32 valid KV positions per
     request (the query sits at position ctx[b] - 1).  Position p of
-    request b lives at pool slot (tbl[b, p // bs], p % bs).
+    request b lives at pool slot (tbl[b, p // bs], :, p % bs).
     """
     B, Sq, H, D = q.shape
-    P, bs, Kv, _ = k_pool.shape
+    P, Kv, bs, _ = k_pool.shape
     G = H // Kv
     nb = tbl.shape[1]
     safe = jnp.clip(tbl, 0, P - 1)
-    k = k_pool[safe].reshape(B, nb * bs, Kv, D)          # (B, Skv, Kv, D)
-    v = v_pool[safe].reshape(B, nb * bs, Kv, D)
+
+    def gather(pool):                                    # -> (B, Skv, Kv, D)
+        return pool[safe].transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, Kv, D)
+
+    k, v = gather(k_pool), gather(v_pool)
     k_pos = jnp.arange(nb * bs)
     valid = (k_pos[None] < ctx[:, None]) & \
         (tbl >= 0).repeat(bs, axis=1)                    # (B, Skv)

@@ -36,15 +36,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_scr,
     u = u_ref[0].astype(jnp.float32)                    # (1, N)
     S = s_scr[...]                                      # (N, N)
 
-    lw = jnp.log(jnp.maximum(w, 1e-12))
-    lc = jnp.cumsum(lw, axis=0)                         # inclusive
-    lc_prev = lc - lw
-    qp = r * jnp.exp(lc_prev)
-    kp = k * jnp.exp(-lc)
-
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     tri = (jj < ii).astype(jnp.float32)                 # strictly lower
+
+    lw = jnp.log(jnp.maximum(w, 1e-12))
+    # inclusive cumsum over the chunk as a lower-triangular matmul (Mosaic
+    # has no cumsum lowering); full f32 precision keeps the decay exact
+    lc = jax.lax.dot(tri + (jj == ii).astype(jnp.float32), lw,
+                     precision=jax.lax.Precision.HIGHEST)
+    lc_prev = lc - lw
+    qp = r * jnp.exp(lc_prev)
+    kp = k * jnp.exp(-lc)
 
     A = jax.lax.dot_general(qp, kp, (((1,), (1,)), ((), ()))) * tri
     diag = jnp.sum(r * u * k, axis=1, keepdims=True)    # (C, 1)
@@ -138,6 +141,7 @@ def _wkv6_forward(r, k, v, w, u, chunk, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
+        name="wkv6_fwd",
     )(rb, kb, vb, wb, ub)
 
     y = y.reshape(B, H, Tp, N).transpose(0, 2, 1, 3)[:, :T]

@@ -1,7 +1,7 @@
 import os
 import sys
 
-from repro.launch.devices import force_host_device_count
+from repro.launch.devices import enable_compile_cache, force_host_device_count
 
 
 def _force_fake_devices(argv):
@@ -52,9 +52,6 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
 
 from repro import strategy as strategy_lib
 from repro.configs import SHAPES, get_config, list_archs, supports_shape
@@ -239,8 +236,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # older jax returns [dict]
-            cost = cost[0] if cost else {}
         # trip-count-scaled: while bodies multiplied by known_trip_count
         coll = collective_stats(compiled.as_text())
         n_dev = plan.mesh.devices.size          # chips in THIS mesh
@@ -409,6 +404,7 @@ def main():
                     help="write per-config lower/compile spans as a "
                          "Chrome-trace/Perfetto JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
     rt_overrides = {}
     if args.kernels:
         rt_overrides["attn_impl"] = args.kernels

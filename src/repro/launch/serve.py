@@ -19,8 +19,17 @@ import jax.numpy as jnp
 from repro import strategy as strategy_lib
 from repro.configs import ShapeConfig, get_config, reduced
 from repro.core import parallel as par
+from repro.launch.devices import enable_compile_cache
 from repro.models import Runtime, init_params
 from repro.serve import ServeEngine
+
+
+def single_device_runtime(kernels: str) -> Runtime:
+    """The Runtime of the single-device serving path: 'auto' picks the
+    dense MoE oracle for small token counts and the dropping dispatch
+    above the threshold; ``kernels='pallas'`` runs flash-decode."""
+    return Runtime(rwkv_chunk=16, mamba_chunk=32, moe_impl="auto",
+                   attn_impl=kernels, norm_impl=kernels)
 
 
 def main():
@@ -54,6 +63,7 @@ def main():
     ap.add_argument("--metrics_jsonl", default="",
                     help="stream every telemetry event as JSONL here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -80,10 +90,7 @@ def main():
             cfg, plan, jax.eval_shape(lambda: params))
         params = jax.device_put(params, pshard)
     else:
-        # single-device path: 'auto' picks the dense oracle for small
-        # token counts and the dropping dispatch above the threshold
-        rt = Runtime(rwkv_chunk=16, mamba_chunk=32, moe_impl="auto",
-                     attn_impl=args.kernels, norm_impl=args.kernels)
+        rt = single_device_runtime(args.kernels)
         params = init_params(cfg, key)
     from repro import telemetry as tel
     recorder = tel.NULL

@@ -1,7 +1,8 @@
 """Training driver: ``python -m repro.launch.train --arch qwen3-0.6b ...``
 
-Runs real training on whatever devices exist (CPU here; the same code path
-lowers for the production TPU mesh — the topology is the only delta).
+Runs real training on whatever devices exist: virtual CPU devices for
+tests, or the TPU chips of one host (``chip_smoke.py`` drives this path on
+a v5e).  The compile cache follows ``launch.devices.enable_compile_cache``.
 
 Strategy selection goes through the unified API (``repro.strategy``):
 
@@ -20,7 +21,7 @@ import argparse
 import os
 import sys
 
-from repro.launch.devices import force_host_device_count
+from repro.launch.devices import enable_compile_cache, force_host_device_count
 
 
 def _force_host_devices(argv):
@@ -51,6 +52,15 @@ from repro.core import parallel as par
 from repro.data import Batcher, BinTokenSource, SyntheticSource
 from repro.optim import AdamWConfig
 from repro.train.trainer import TrainConfig, train_loop
+
+
+def runtime_overrides(kernels: str, seq_len: int) -> dict:
+    """The Runtime knobs this entry point trains with, on top of what the
+    plan's ``make_runtime`` derives (dtypes, constraints, pipeline)."""
+    return dict(remat=False, rwkv_chunk=32, mamba_chunk=64,
+                attn_impl=kernels, norm_impl=kernels,
+                attn_min_chunked_len=max(2048, seq_len + 1)
+                if seq_len <= 2048 else 2048)
 
 
 def main():
@@ -111,6 +121,7 @@ def main():
                     help="write per-window predicted-vs-measured step-time "
                          "drift (cost model vs telemetry spans) here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -134,11 +145,8 @@ def main():
     # dtypes come from the strategy's precision policy (plan.policy): the
     # default/_f32 spec keeps pure f32, a _bf16 spec trains bf16 with f32
     # master params, _fp8 additionally quantizes the ZeRO gather wire
-    rt = par.make_runtime(cfg, plan, shape,
-                          remat=False, rwkv_chunk=32, mamba_chunk=64,
-                          attn_impl=args.kernels, norm_impl=args.kernels,
-                          attn_min_chunked_len=max(2048, args.seq_len + 1)
-                          if args.seq_len <= 2048 else 2048)
+    rt_overrides = runtime_overrides(args.kernels, args.seq_len)
+    rt = par.make_runtime(cfg, plan, shape, **rt_overrides)
 
     def make_batches():
         # fresh per attempt: sources are stateful; a resumed attempt
@@ -197,11 +205,6 @@ def main():
     if args.max_restarts > 0:
         from repro.resilience.supervisor import (SupervisorConfig,
                                                  supervise_training)
-        rt_overrides = dict(
-            remat=False, rwkv_chunk=32, mamba_chunk=64,
-            attn_impl=args.kernels, norm_impl=args.kernels,
-            attn_min_chunked_len=max(2048, args.seq_len + 1)
-            if args.seq_len <= 2048 else 2048)
         params, opt_state, history, sup = supervise_training(
             cfg, strat, topo, shape, tc, make_batches,
             rt_overrides=rt_overrides, key=jax.random.PRNGKey(args.seed),

@@ -146,7 +146,9 @@ def sdpa_causal(q, k, v, window=0, rt: Optional[Runtime] = None):
     if rt.attn_impl == "pallas" and S >= 128 and q.shape[-1] % 64 == 0:
         # TPU hot path: Pallas flash kernel (interpret-mode on CPU)
         from repro.kernels import ops as kernel_ops
-        return kernel_ops.attention(q, k, v, window=window)
+        return rt.per_shard(
+            lambda q, k, v: kernel_ops.attention(q, k, v, window=window),
+            q, k, v, heads=True)
     if S <= rt.attn_min_chunked_len:
         pos = jnp.arange(S)
         return _attend_dense(q, k, v, pos, pos, window, scale)
@@ -179,22 +181,22 @@ def sdpa_decode(q, k_cache, v_cache, k_pos, cur_pos, window=0):
 # ---------------------------------------------------------------------------
 
 def _paged_write(pool, vals, tbl, pos):
-    """Scatter vals (B, S, Kv, D) into pool (P, bs, Kv, D) at absolute
+    """Scatter vals (B, S, Kv, D) into pool (P, Kv, bs, D) at absolute
     positions pos (B, S) via the block table tbl (B, max_blocks).
 
-    Position p of request b lands at (tbl[b, p // bs], p % bs).  Writes
+    Position p of request b lands at (tbl[b, p // bs], :, p % bs).  Writes
     to unallocated blocks (tbl -1) or past the table are *dropped* — this
     is what makes inactive slots in a fixed-shape decode batch harmless:
     their sentinel positions fall outside any allocated block.
     """
-    P, bs = pool.shape[0], pool.shape[1]
+    P, bs = pool.shape[0], pool.shape[2]
     nb = tbl.shape[1]
     blk_log = pos // bs
     blk = jnp.take_along_axis(tbl, jnp.clip(blk_log, 0, nb - 1), axis=1)
     blk = jnp.where((blk < 0) | (blk_log >= nb), P, blk)   # P = out of bounds
     off = pos % bs
     B, S = pos.shape
-    return pool.at[blk.reshape(-1), off.reshape(-1)].set(
+    return pool.at[blk.reshape(-1), :, off.reshape(-1)].set(
         vals.reshape((B * S,) + vals.shape[2:]).astype(pool.dtype),
         mode="drop")
 
@@ -208,12 +210,12 @@ def _paged_attend(q, k_pool, v_pool, tbl, q_pos, n_valid, window=0):
     included), so both chunked prefill (Sq > 1) and decode (Sq == 1) are
     the same computation.
     """
-    P, bs, Kv, D = k_pool.shape
+    P, Kv, bs, D = k_pool.shape
     B, Sq = q_pos.shape
     nb = tbl.shape[1]
     safe = jnp.clip(tbl, 0, P - 1)
-    k = k_pool[safe].reshape(B, nb * bs, Kv, D)
-    v = v_pool[safe].reshape(B, nb * bs, Kv, D)
+    k = k_pool[safe].transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, Kv, D)
+    v = v_pool[safe].transpose(0, 1, 3, 2, 4).reshape(B, nb * bs, Kv, D)
     k_pos = jnp.broadcast_to(jnp.arange(nb * bs)[None], (B, nb * bs))
     valid = (k_pos < n_valid[:, None]) & (tbl >= 0).repeat(bs, axis=1)
     mask = valid[:, None, :] & (k_pos[:, None, :] <= q_pos[:, :, None])

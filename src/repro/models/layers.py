@@ -77,12 +77,26 @@ class Runtime:
     expert_axis: str = ""               # mesh axis of the EP all-to-all
     expert_mesh: Optional[object] = None     # Mesh the EP shard_map runs over
     expert_token_axes: tuple = ()       # mesh axes sharding the token dim
+    kernel_shard: Optional[Callable] = None
+                                        # (fn, *args, heads) -> fn per device
+                                        # shard under the plan's mesh; set by
+                                        # parallel.make_runtime (GSPMD cannot
+                                        # partition a Pallas TPU kernel)
 
     def c(self, name: str, x):
         """Apply a named sharding constraint if a parallel plan is active."""
         if self.constrain is None:
             return x
         return self.constrain(name, x)
+
+    def per_shard(self, fn, *args, heads: bool = False):
+        """Call a Pallas kernel wrapper ``fn(*args)``: directly without a
+        multi-device plan, else on each device's shard of the operands
+        (``parallel.make_kernel_sharder``; ``heads`` marks attention
+        operands whose dim 2 may shard over the model axis)."""
+        if self.kernel_shard is None:
+            return fn(*args)
+        return self.kernel_shard(fn, *args, heads=heads)
 
 
 DEFAULT_RUNTIME = Runtime()
@@ -126,7 +140,8 @@ def apply_norm(p, x, eps, rt: Optional["Runtime"] = None):
         # fused Pallas rmsnorm (custom_vjp: backward is a kernel too);
         # layernorm and non-lane-aligned dims stay on the jnp path
         from repro.kernels import ops as kernel_ops
-        return kernel_ops.rmsnorm(x, p["scale"], eps=eps)
+        return rt.per_shard(lambda x, s: kernel_ops.rmsnorm(x, s, eps=eps),
+                            x, p["scale"])
     xf = x.astype(jnp.float32)
     if "bias" in p:  # layernorm
         mu = xf.mean(-1, keepdims=True)

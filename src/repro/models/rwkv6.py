@@ -191,7 +191,10 @@ def rwkv_time_mix(cfg, p, x, rt: Runtime, state=None):
           and N in (16, 32, 64, 128)):
         # TPU hot path: Pallas chunked WKV kernel (zero initial state)
         from repro.kernels import ops as kernel_ops
-        y, S = kernel_ops.wkv6(r, k, v, w, p["u"], chunk=rt.rwkv_chunk)
+        y, S = rt.per_shard(
+            lambda r, k, v, w, u: kernel_ops.wkv6(r, k, v, w, u,
+                                                  chunk=rt.rwkv_chunk),
+            r, k, v, w, p["u"])
     else:
         y, S = wkv_chunked(r, k, v, w, p["u"], S0, rt.rwkv_chunk)
 

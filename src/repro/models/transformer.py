@@ -396,6 +396,7 @@ def pipeline_stage_runtime(rt: Runtime, rows: int) -> Runtime:
                 "grad_accum x microbatches")
         moe_impl = "ep_manual"
     return dataclasses.replace(rt, constrain=None, gather_params=None,
+                               kernel_shard=None,
                                moe_stat_axes=tok_axes, moe_groups=1,
                                moe_impl=moe_impl,
                                tp_reduce_axis=rt.pipeline_tp_axis,

@@ -147,3 +147,22 @@ def collective_stats_flat(hlo_text: str) -> Dict[str, Dict[str, float]]:
 
 def total_collective_bytes(hlo_text: str) -> int:
     return int(sum(v["bytes"] for v in collective_stats(hlo_text).values()))
+
+
+_KERNEL_RE = re.compile(
+    r"%([A-Za-z_][\w\-]*?)(?:\.\d+)*\s*=.*custom_call_target=\"tpu_custom_call\"")
+
+
+def pallas_kernels(hlo_text: str) -> Dict[str, int]:
+    """{kernel name: count} of the Pallas TPU kernels in compiled HLO text.
+
+    A ``pallas_call(..., name=n)`` compiles to a ``tpu_custom_call``
+    instruction named ``%n`` (plus XLA's ``.<k>`` suffixes), so this says
+    which kernels survived into the program and did not fall back to jnp.
+    """
+    out: Dict[str, int] = defaultdict(int)
+    for line in hlo_text.splitlines():
+        m = _KERNEL_RE.search(line)
+        if m:
+            out[m.group(1)] += 1
+    return dict(out)

@@ -4,8 +4,10 @@ The dense decode cache allocates ``max_len`` KV slots per request up front,
 so a 32-token chat and a 32k-token document pay the same HBM.  The paged
 cache (vLLM-style) splits KV storage into fixed-size *blocks*:
 
-  * every attention layer owns a pool ``k_pool/v_pool (P, bs, Kv, D)`` —
-    P blocks of bs positions each, shared by all in-flight requests;
+  * every attention layer owns a pool ``k_pool/v_pool (P, Kv, bs, D)`` —
+    P blocks of bs positions each, shared by all in-flight requests; a
+    block keeps each kv head's (bs, D) slab whole, which is the tile the
+    flash-decode kernel loads;
   * each request holds a *block table* row ``tbl (max_blocks,)`` mapping
     its logical block i to a pool block id (-1 = unallocated) and a
     context length ``ctx`` counting KV entries written so far;
@@ -19,7 +21,7 @@ lengths are *shared* read-only state passed alongside (``cache['paged']``)
 — layers never mutate them, the engine advances ``ctx`` between steps so
 every layer stays in sync by construction.
 
-Absolute position p of request b lives at ``(tbl[b, p // bs], p % bs)``.
+Absolute position p of request b lives at ``(tbl[b, p // bs], :, p % bs)``.
 """
 from __future__ import annotations
 
@@ -119,8 +121,8 @@ def init_paged_pools(cfg, n_blocks: int, block_size: int, dtype,
 
     def one_layer():
         return {"kv": {
-            "k_pool": jnp.zeros((n_blocks, block_size, kv, hd), dtype),
-            "v_pool": jnp.zeros((n_blocks, block_size, kv, hd), dtype),
+            "k_pool": jnp.zeros((n_blocks, kv, block_size, hd), dtype),
+            "v_pool": jnp.zeros((n_blocks, kv, block_size, hd), dtype),
         }}
 
     prefix, start, period, nb = layer_plan(cfg)

@@ -51,17 +51,33 @@ class Topology:
         return max(1, self.n_devices // self.island)
 
 
+# jax ``device_kind`` -> (costmodel.HARDWARE key, per-chip HBM bytes)
+DEVICE_PROFILES = {
+    "TPU v5 lite": ("TPUv5e", 16e9),
+}
+
+
 def host_topology(hardware: str = "H100", hbm: float = 80e9,
                   n_devices: Optional[int] = None) -> Topology:
     """Whatever devices this process sees, as one fast island.
 
-    ``hardware`` picks the cost-model profile the planner uses when asked
-    to rank strategies for the host mesh (CPU smoke runs have no profile of
-    their own — predictions are for the named generation, execution is
-    local).
+    On an accelerator the cost-model profile and HBM come from the
+    device's ``device_kind`` through ``DEVICE_PROFILES``; a kind missing
+    from the table raises rather than planning against another chip.  On
+    the CPU, which has no profile of its own, ``hardware``/``hbm`` name
+    the generation the planner assumes — a planning assumption, not a
+    measurement.
     """
     import jax
-    n = n_devices or len(jax.devices())
+    devices = jax.devices()
+    n = n_devices or len(devices)
+    if devices[0].platform != "cpu":
+        kind = devices[0].device_kind
+        if kind not in DEVICE_PROFILES:
+            raise ValueError(
+                f"no cost-model profile for device kind {kind!r}; known: "
+                f"{sorted(DEVICE_PROFILES)}")
+        hardware, hbm = DEVICE_PROFILES[kind]
     return Topology("host", n, island=n, hardware=hardware, hbm=hbm)
 
 
@@ -115,8 +131,5 @@ def build_mesh(topology: Topology, model: int = 1, pods: int = 1,
             if a in ("data", "model") or s > 1]
     shape = tuple(shape[i] for i in keep)
     axes = tuple(axes[i] for i in keep)
-    if abstract:
-        from jax.sharding import AbstractMesh
-        return AbstractMesh(tuple(zip(axes, shape)))
-    import jax
-    return jax.make_mesh(shape, axes)
+    from repro.core.compat import abstract_mesh, make_mesh
+    return (abstract_mesh if abstract else make_mesh)(shape, axes)

@@ -111,6 +111,14 @@ def make_train_step(cfg: ModelConfig, rt: Runtime, tc: TrainConfig,
     return train_step
 
 
+def jit_train_step(step_fn, pshard, oshard, bshard):
+    """The step as ``train_loop`` compiles it: sharded inputs and outputs,
+    params and optimizer state donated."""
+    return jax.jit(step_fn, in_shardings=(pshard, oshard, bshard),
+                   out_shardings=(pshard, oshard, None),
+                   donate_argnums=(0, 1))
+
+
 def place_train_state(cfg: ModelConfig, plan: par.ParallelPlan, params,
                       opt_state, batch):
     """device_put existing (params, opt_state, batch) into the plan's
@@ -262,10 +270,7 @@ def train_loop(cfg: ModelConfig, plan: par.ParallelPlan, rt: Runtime,
                 next(it)
         first = next(it)
         bshard = par.batch_specs(cfg, plan, first)
-        jstep = jax.jit(step_fn,
-                        in_shardings=(pshard, oshard, bshard),
-                        out_shardings=(pshard, oshard, None),
-                        donate_argnums=(0, 1))
+        jstep = jit_train_step(step_fn, pshard, oshard, bshard)
 
         history = []
         t0 = time.time()

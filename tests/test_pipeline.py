@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config, reduced
-from repro.core.parallel import use_mesh
+from repro.core.compat import make_mesh, use_mesh
 from repro.core.pipeline import (batch_axes_spec, bubble_fraction,
                                  make_pipelined_block_fn, pipeline_apply)
 from repro.models.layers import Runtime
@@ -42,10 +42,10 @@ def test_pipeline_matches_sequential_fwd_and_grad(setup, eight_devices,
                                                   mesh_axes):
     cfg, rt, layers, stacked = setup
     if mesh_axes == ("pipe",):
-        mesh = jax.make_mesh((4,), mesh_axes, devices=eight_devices[:4])
+        mesh = make_mesh((4,), mesh_axes, devices=eight_devices[:4])
         batch_axes = ()
     else:
-        mesh = jax.make_mesh((4, 2), mesh_axes, devices=eight_devices)
+        mesh = make_mesh((4, 2), mesh_axes, devices=eight_devices)
         batch_axes = ("data",)
     M, mb, S, d = 8, 2, 16, cfg.d_model
     x = jax.random.normal(jax.random.PRNGKey(0), (M, mb, S, d)) * 0.5
@@ -79,7 +79,7 @@ def test_pipeline_matches_sequential_fwd_and_grad(setup, eight_devices,
 def test_pipeline_multi_layer_stages(setup, eight_devices):
     """4 layers over 2 stages: each stage scans its 2-layer local slice."""
     cfg, rt, layers, stacked = setup
-    mesh = jax.make_mesh((2,), ("pipe",), devices=eight_devices[:2])
+    mesh = make_mesh((2,), ("pipe",), devices=eight_devices[:2])
     M, mb, S, d = 4, 2, 16, cfg.d_model
     x = jax.random.normal(jax.random.PRNGKey(1), (M, mb, S, d)) * 0.5
     stage_fn = make_pipelined_block_fn(cfg, rt)
@@ -97,7 +97,7 @@ def test_bubble_fraction_formula():
 
 
 def test_batch_axes_spec_fit_or_drop(eight_devices):
-    mesh = jax.make_mesh((2, 4), ("pipe", "data"), devices=eight_devices)
+    mesh = make_mesh((2, 4), ("pipe", "data"), devices=eight_devices)
     assert batch_axes_spec(mesh, ("data",), 8) == ("data",)
     assert batch_axes_spec(mesh, ("data",), 3) == ()   # not divisible
     assert batch_axes_spec(mesh, ("data",), 1) == ()   # cannot occupy

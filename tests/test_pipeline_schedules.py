@@ -13,7 +13,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs import get_config, reduced
-from repro.core.parallel import use_mesh
+from repro.core.compat import make_mesh, use_mesh
 from repro.core.pipeline import (SCHEDULES, batch_axes_spec, bubble_fraction,
                                  get_schedule, inflight_microbatches,
                                  known_schedule, make_pipelined_block_fn,
@@ -196,10 +196,10 @@ def test_1f1b_matches_sequential_fwd_and_grad(setup, eight_devices,
     (pipe, data) mesh and gradients w.r.t. params AND inputs."""
     cfg, rt, layers, stacked = setup
     if mesh_axes == ("pipe",):
-        mesh = jax.make_mesh((4,), mesh_axes, devices=eight_devices[:4])
+        mesh = make_mesh((4,), mesh_axes, devices=eight_devices[:4])
         batch_axes = ()
     else:
-        mesh = jax.make_mesh((4, 2), mesh_axes, devices=eight_devices)
+        mesh = make_mesh((4, 2), mesh_axes, devices=eight_devices)
         batch_axes = ("data",)
     M, mb, S, d = 8, 2, 16, cfg.d_model
     x = jax.random.normal(jax.random.PRNGKey(0), (M, mb, S, d)) * 0.5
@@ -238,7 +238,7 @@ def test_all_schedules_equal_gpipe_execution(setup, eight_devices):
     executor's split dgrad/wgrad backward and the interleaved
     non-contiguous stage chunking (L=4 % (P=2 * v=2) == 0)."""
     cfg, rt, layers, stacked = setup
-    mesh = jax.make_mesh((2,), ("pipe",), devices=eight_devices[:2])
+    mesh = make_mesh((2,), ("pipe",), devices=eight_devices[:2])
     M, mb, S, d = 4, 2, 16, cfg.d_model
     x = jax.random.normal(jax.random.PRNGKey(1), (M, mb, S, d)) * 0.5
     stage_fn = make_pipelined_block_fn(cfg, rt)
@@ -270,8 +270,7 @@ def test_frontier_schedules_match_sequential_composed_mesh(
     w.r.t. params and inputs, with the interleaved param permutation
     un-permuting its cotangents."""
     cfg, rt, layers, stacked = setup
-    mesh = jax.make_mesh((2, 2), ("pipe", "data"),
-                         devices=eight_devices[:4])
+    mesh = make_mesh((2, 2), ("pipe", "data"), devices=eight_devices[:4])
     M, mb, S, d = 4, 2, 16, cfg.d_model
     x = jax.random.normal(jax.random.PRNGKey(3), (M, mb, S, d)) * 0.5
     stage_fn = make_pipelined_block_fn(cfg, rt)
@@ -306,14 +305,14 @@ def test_interleaved_apply_rejects_bad_chunking(setup, eight_devices):
     """L % (P*v) != 0 and M % P != 0 are construction errors, not silent
     truncation."""
     cfg, rt, layers, stacked = setup
-    mesh = jax.make_mesh((4,), ("pipe",), devices=eight_devices[:4])
+    mesh = make_mesh((4,), ("pipe",), devices=eight_devices[:4])
     stage_fn = make_pipelined_block_fn(cfg, rt)
     x = jnp.zeros((8, 2, 16, cfg.d_model))
     with pytest.raises(ValueError):       # 4 layers % (4 stages * 2) != 0
         with use_mesh(mesh):
             pipeline_apply(stage_fn, stacked, x, mesh, "pipe",
                            schedule="1f1b_i2")
-    mesh2 = jax.make_mesh((2,), ("pipe",), devices=eight_devices[:2])
+    mesh2 = make_mesh((2,), ("pipe",), devices=eight_devices[:2])
     x2 = jnp.zeros((3, 2, 16, cfg.d_model))
     with pytest.raises(ValueError):       # M=3 % P=2 != 0
         with use_mesh(mesh2):
@@ -327,7 +326,7 @@ def test_measured_memory_ordering_gpipe_vs_1f1b(setup, eight_devices):
     model's in-flight term predicts — gpipe holds all M=8 microbatch
     activations, 1f1b caps at P=4."""
     cfg, rt, layers, stacked = setup
-    mesh = jax.make_mesh((4,), ("pipe",), devices=eight_devices[:4])
+    mesh = make_mesh((4,), ("pipe",), devices=eight_devices[:4])
     M, mb, S, d = 8, 2, 16, cfg.d_model
     x = jax.random.normal(jax.random.PRNGKey(4), (M, mb, S, d)) * 0.5
     stage_fn = make_pipelined_block_fn(cfg, rt)
@@ -352,7 +351,7 @@ def test_measured_memory_ordering_gpipe_vs_1f1b(setup, eight_devices):
 
 def test_1f1b_apply_rejects_underfilled(setup, eight_devices):
     cfg, rt, layers, stacked = setup
-    mesh = jax.make_mesh((4,), ("pipe",), devices=eight_devices[:4])
+    mesh = make_mesh((4,), ("pipe",), devices=eight_devices[:4])
     x = jnp.zeros((2, 2, 16, cfg.d_model))       # M=2 < P=4
     stage_fn = make_pipelined_block_fn(cfg, rt)
     with pytest.raises(ValueError):
@@ -445,7 +444,7 @@ def test_batch_axes_spec_warns_once_on_dropped_axis(eight_devices, caplog):
     replicated (redundant) data-parallel compute; that used to be fully
     silent — now it logs a warning, once per configuration."""
     import repro.core.pipeline as pl
-    mesh = jax.make_mesh((2, 4), ("pipe", "data"), devices=eight_devices)
+    mesh = make_mesh((2, 4), ("pipe", "data"), devices=eight_devices)
     pl._warned_dropped.clear()
     with caplog.at_level(logging.WARNING, logger="repro.core.pipeline"):
         kept = batch_axes_spec(mesh, ("data",), 3)      # 3 % 4 -> dropped

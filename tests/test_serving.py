@@ -77,8 +77,8 @@ def test_flash_decode_matches_oracle(heads, kv_heads):
     B, D, bs, P, nb = 3, 16, 8, 32, 6
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, 1, heads, D))
-    k_pool = jax.random.normal(ks[1], (P, bs, kv_heads, D))
-    v_pool = jax.random.normal(ks[2], (P, bs, kv_heads, D))
+    k_pool = jax.random.normal(ks[1], (P, kv_heads, bs, D))
+    v_pool = jax.random.normal(ks[2], (P, kv_heads, bs, D))
     # ragged contexts, distinct pool blocks per request, tail unallocated
     ctx = jnp.asarray([5, bs * 3, bs * nb], jnp.int32)
     perm = jax.random.permutation(ks[3], P)[:B * nb].reshape(B, nb)
@@ -104,8 +104,8 @@ def test_paged_ref_layout_invariance():
     k = jax.random.normal(ks[1], (B, S, Kv, D))
     v = jax.random.normal(ks[2], (B, S, Kv, D))
     nb = S // bs
-    k_pool = k.reshape(B * nb, bs, Kv, D)
-    v_pool = v.reshape(B * nb, bs, Kv, D)
+    k_pool = k.reshape(B * nb, bs, Kv, D).transpose(0, 2, 1, 3)
+    v_pool = v.reshape(B * nb, bs, Kv, D).transpose(0, 2, 1, 3)
     tbl = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     ctx = jnp.full((B,), S, jnp.int32)
     paged = paged_attention_ref(q, k_pool, v_pool, tbl, ctx)

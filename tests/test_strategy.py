@@ -517,6 +517,57 @@ def test_get_topology_names():
         strategy_lib.get_topology("cluster9000")
 
 
+def test_build_mesh_axes_are_auto():
+    """GSPMD plans need Auto axes: under Explicit ones a sharding
+    constraint is an assertion and the embedding gather cannot resolve."""
+    from jax.sharding import AxisType
+    topo = strategy_lib.host_topology(n_devices=1)
+    for abstract in (False, True):
+        m = strategy_lib.build_mesh(topo, abstract=abstract)
+        assert set(m.axis_types) == {AxisType.Auto}
+
+
+class _StubDevice:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_host_topology_reads_device_kind(monkeypatch):
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_StubDevice("TPU v5 lite")] * 4)
+    topo = strategy_lib.host_topology()
+    assert (topo.hardware, topo.hbm, topo.n_devices) == ("TPUv5e", 16e9, 4)
+    assert topo.hw is cm.TPU_V5E
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_StubDevice("TPU v99 mystery")])
+    with pytest.raises(ValueError, match="TPU v99 mystery"):
+        strategy_lib.host_topology()
+
+
+def test_host_topology_cpu_keeps_named_profile():
+    topo = strategy_lib.host_topology()
+    assert (topo.hardware, topo.hbm) == ("H100", 80e9)
+
+
+def test_compile_cache_dir_honours_env(monkeypatch):
+    from repro.launch import devices
+    from repro.perf.paths import REPO_ROOT
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(devices.CACHE_ENV, "/elsewhere/cache")
+    assert devices.enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == was     # JAX reads env
+    monkeypatch.delenv(devices.CACHE_ENV)
+    fixed = devices.compile_cache_dir()
+    assert fixed == f"{REPO_ROOT}/.jax_cache"
+    try:
+        assert devices.enable_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_decode_cache_axes_long_context():
     s = parse("hsdp_tp16")
     plan = s.to_plan(get_config("qwen3-0.6b"), POD1, SHAPES["long_500k"],

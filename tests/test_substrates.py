@@ -142,8 +142,8 @@ def test_collective_stats_on_real_lowering():
         from jax.sharding import PartitionSpec as P
         import sys; sys.path.insert(0, "src")
         from repro.perf.hlo import collective_stats
-        from repro.core.parallel import use_mesh
-        mesh = jax.make_mesh((4,), ("x",))
+        from repro.core.compat import make_mesh, use_mesh
+        mesh = make_mesh((4,), ("x",))
         def f(a):
             b = jax.lax.with_sharding_constraint(a, jax.NamedSharding(mesh, P("x")))
             def body(c, x): return c + (b * x).sum(), None

@@ -32,6 +32,18 @@ from repro.models.layers import (Runtime, apply_norm, embed_tokens,
                                  lm_logits, mrope_angles, rope_angles,
                                  tp_reduce_out)
 
+# Named scopes of the forward pass and the loss.  Each lands in the
+# ``op_name`` of every compiled instruction it covers, and survives
+# fusion, so a profile can be split by part: the forward op reads
+# ``.../jvp(blocks)/...``, its backward ``.../transpose(jvp(blocks))/...``.
+SCOPE_EMBED = "embed"
+SCOPE_BLOCKS = "blocks"          # prefix layers, layer scan, pipeline
+SCOPE_FINAL_NORM = "final_norm"
+SCOPE_LM_HEAD = "lm_head"
+SCOPE_XENT_LOSS = "xent_loss"    # cross entropy on the logits
+MODEL_SCOPES = (SCOPE_EMBED, SCOPE_BLOCKS, SCOPE_FINAL_NORM,
+                SCOPE_LM_HEAD, SCOPE_XENT_LOSS)
+
 
 # ---------------------------------------------------------------------------
 # layer planning
@@ -254,7 +266,8 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
     positions = offset + jnp.arange(S, dtype=jnp.int32)[None]
     positions = jnp.broadcast_to(positions, (B, S))
 
-    h = _embed_inputs(cfg, params, batch, rt, positions)
+    with jax.named_scope(SCOPE_EMBED):
+        h = _embed_inputs(cfg, params, batch, rt, positions)
     rope_ang = _rope_for(cfg, batch, positions)
 
     prefix, start, period, n_blocks = layer_plan(cfg)
@@ -274,16 +287,16 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
             raise ValueError(
                 "pipeline runtime requires a uniform layer stack "
                 "(no prefix, period 1); Strategy.to_plan validates this")
-        h, aux_total = _pipeline_blocks(cfg, params, h, rope_ang, rt)
-        h = apply_norm(params["final_norm"], h, cfg.norm_eps, rt)
-        logits = lm_logits(params["embed"], h, rt)
-        return logits, None, aux_total
+        with jax.named_scope(SCOPE_BLOCKS):
+            h, aux_total = _pipeline_blocks(cfg, params, h, rope_ang, rt)
+        return _head(cfg, params, h, rt), None, aux_total
 
     new_prefix_caches = []
     for j, i in enumerate(prefix):
         c = None if cache is None else cache["prefix"][j]
-        h, nc, aux = _apply_layer(cfg, _sig(cfg, i), params["prefix"][j],
-                                  h, rope_ang, rt, c, paged)
+        with jax.named_scope(SCOPE_BLOCKS):
+            h, nc, aux = _apply_layer(cfg, _sig(cfg, i), params["prefix"][j],
+                                      h, rope_ang, rt, c, paged)
         aux_total += aux
         new_prefix_caches.append(nc)
 
@@ -331,28 +344,28 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
         if rt.remat:
             block_fn = jax.checkpoint(block_fn)
 
-        blocks = tuple(params["blocks"])
-        if prefetch:
-            # feed each iteration the next slice (rolled stack; the final
-            # iteration's wrapped-around gather is dead and DCEs away) and
-            # seed the buffer with slice 0's gather
-            xs = tuple(jax.tree.map(lambda a: jnp.roll(a, -1, axis=0), b)
-                       for b in blocks)
-            g0 = tuple(rt.gather_params(jax.tree.map(lambda a: a[0], b))
-                       for b in blocks)
-            carry0 = (h, aux_total, g0)
-        else:
-            xs = blocks
-            carry0 = (h, aux_total)
-        if cache is not None:
-            xs = xs + tuple(cache["blocks"])
-        out_carry, ys = jax.lax.scan(block_fn, carry0, xs)
+        with jax.named_scope(SCOPE_BLOCKS):
+            blocks = tuple(params["blocks"])
+            if prefetch:
+                # feed each iteration the next slice (rolled stack; the
+                # final iteration's wrapped-around gather is dead and DCEs
+                # away) and seed the buffer with slice 0's gather
+                xs = tuple(jax.tree.map(lambda a: jnp.roll(a, -1, axis=0), b)
+                           for b in blocks)
+                g0 = tuple(rt.gather_params(jax.tree.map(lambda a: a[0], b))
+                           for b in blocks)
+                carry0 = (h, aux_total, g0)
+            else:
+                xs = blocks
+                carry0 = (h, aux_total)
+            if cache is not None:
+                xs = xs + tuple(cache["blocks"])
+            out_carry, ys = jax.lax.scan(block_fn, carry0, xs)
         h, aux_total = out_carry[0], out_carry[1]
         if cache is not None:
             new_block_caches = list(ys)
 
-    h = apply_norm(params["final_norm"], h, cfg.norm_eps, rt)
-    logits = lm_logits(params["embed"], h, rt)
+    logits = _head(cfg, params, h, rt)
 
     new_cache = None
     if cache is not None:
@@ -360,6 +373,14 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
         if paged is not None:
             new_cache["paged"] = paged
     return logits, new_cache, aux_total
+
+
+def _head(cfg: ModelConfig, params, h, rt: Runtime):
+    """Final norm and the LM head -> logits."""
+    with jax.named_scope(SCOPE_FINAL_NORM):
+        h = apply_norm(params["final_norm"], h, cfg.norm_eps, rt)
+    with jax.named_scope(SCOPE_LM_HEAD):
+        return lm_logits(params["embed"], h, rt)
 
 
 def pipeline_stage_runtime(rt: Runtime, rows: int) -> Runtime:
@@ -469,14 +490,15 @@ def _pipeline_blocks(cfg: ModelConfig, params, h, rope_ang, rt: Runtime):
 def loss_fn(cfg: ModelConfig, params, batch, rt: Runtime):
     """Next-token cross entropy; labels < 0 are masked."""
     logits, _, aux = forward(cfg, params, batch, rt)
-    labels = batch["labels"]
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    ll = jnp.take_along_axis(
-        lf, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    nll = ((lse - ll) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    return nll + aux, {"nll": nll, "aux": aux, "ntok": mask.sum()}
+    with jax.named_scope(SCOPE_XENT_LOSS):
+        labels = batch["labels"]
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        ll = jnp.take_along_axis(
+            lf, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
+        mask = (labels >= 0).astype(jnp.float32)
+        nll = ((lse - ll) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        return nll + aux, {"nll": nll, "aux": aux, "ntok": mask.sum()}
 
 
 def prefill(cfg, params, batch, rt: Runtime, max_len: int):

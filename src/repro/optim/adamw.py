@@ -14,6 +14,14 @@ import jax
 import jax.numpy as jnp
 
 
+# Named scopes of the update, in the ``op_name`` of every compiled
+# instruction they cover (see ``models/transformer.py``): the global norm
+# and clip factor, then the per-leaf moment and parameter update.
+SCOPE_GRAD_CLIP = "grad_clip"
+SCOPE_ADAMW = "adamw"
+OPTIMIZER_SCOPES = (SCOPE_GRAD_CLIP, SCOPE_ADAMW)
+
+
 @dataclasses.dataclass(frozen=True)
 class AdamWConfig:
     lr: float = 3e-4
@@ -47,9 +55,10 @@ def _decay_mask(path) -> bool:
 
 def adamw_update(cfg: AdamWConfig, params, grads, state, lr_scale=1.0):
     """-> (new_params, new_state, metrics)."""
-    gnorm = global_norm(grads)
-    clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
-        if cfg.grad_clip else 1.0
+    with jax.named_scope(SCOPE_GRAD_CLIP):
+        gnorm = global_norm(grads)
+        clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
+            if cfg.grad_clip else 1.0
     step = state["step"] + 1
     t = step.astype(jnp.float32)
     bc1 = 1.0 - cfg.b1 ** t
@@ -65,14 +74,15 @@ def adamw_update(cfg: AdamWConfig, params, grads, state, lr_scale=1.0):
             u = u + cfg.weight_decay * p.astype(jnp.float32)
         return (p.astype(jnp.float32) - lr * u).astype(p.dtype), m2, v2
 
-    flat = jax.tree_util.tree_map_with_path(
-        lambda path, p, g, m, v: upd(path, p, g, m, v),
-        params, grads, state["m"], state["v"])
-    new_params = jax.tree.map(lambda t3: t3[0], flat,
-                              is_leaf=lambda x: isinstance(x, tuple))
-    new_m = jax.tree.map(lambda t3: t3[1], flat,
-                         is_leaf=lambda x: isinstance(x, tuple))
-    new_v = jax.tree.map(lambda t3: t3[2], flat,
-                         is_leaf=lambda x: isinstance(x, tuple))
+    with jax.named_scope(SCOPE_ADAMW):
+        flat = jax.tree_util.tree_map_with_path(
+            lambda path, p, g, m, v: upd(path, p, g, m, v),
+            params, grads, state["m"], state["v"])
+        new_params = jax.tree.map(lambda t3: t3[0], flat,
+                                  is_leaf=lambda x: isinstance(x, tuple))
+        new_m = jax.tree.map(lambda t3: t3[1], flat,
+                             is_leaf=lambda x: isinstance(x, tuple))
+        new_v = jax.tree.map(lambda t3: t3[2], flat,
+                             is_leaf=lambda x: isinstance(x, tuple))
     return new_params, {"m": new_m, "v": new_v, "step": step}, \
         {"grad_norm": gnorm, "lr": lr}

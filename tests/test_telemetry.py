@@ -434,6 +434,43 @@ def _tiny_train(telemetry, drift=None, steps=4, fault_plan=None):
                       telemetry=telemetry, drift=drift)
 
 
+def test_train_step_scopes_reach_the_compiled_program():
+    """Each named scope of the train step is in the compiled program's
+    ``op_name`` metadata: the model's both in the forward pass
+    (``/jvp(<scope>)/``) and in the backward (``transpose(jvp(<scope>))``),
+    the optimizer's as they are.  A profile is split by these names."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from repro import strategy as strategy_lib
+    from repro.configs import get_config, reduced
+    from repro.configs.base import ShapeConfig
+    from repro.core import parallel as par
+    from repro.models import transformer as tfm
+    from repro.optim import adamw, init_opt_state
+    from repro.train.trainer import TrainConfig, make_train_step
+
+    cfg = reduced(get_config("qwen3-0.6b"), n_layers=2, d_model=64)
+    shape = ShapeConfig("scopes", 16, 4, "train")
+    plan = strategy_lib.parse("ddp").to_plan(
+        cfg, strategy_lib.host_topology(), shape)
+    step = make_train_step(cfg, par.make_runtime(cfg, plan, shape),
+                           TrainConfig(steps=4, warmup=1))
+    pshapes = jax.eval_shape(lambda k: tfm.init_params(cfg, k),
+                             jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((4, 16), jnp.int32)
+    with par.use_mesh(plan.mesh):
+        text = jax.jit(step).lower(
+            pshapes, jax.eval_shape(init_opt_state, pshapes),
+            {"tokens": tok, "labels": tok}).compile().as_text()
+    for s in tfm.MODEL_SCOPES:
+        assert re.search(rf"/jvp\({s}\)/", text), s
+        assert f"/transpose(jvp({s}))/" in text, s
+    for s in adamw.OPTIMIZER_SCOPES:
+        assert f"/{s}/" in text, s
+
+
 def test_trainer_spans_gauges_and_drift_windows():
     pytest.importorskip("jax")
     rec, mem, _ = make_recorder(time.monotonic)

@@ -193,11 +193,25 @@ def _mixer_kind(cfg: ModelConfig, path) -> str:
 
 
 def _param_spec(cfg: ModelConfig, plan: ParallelPlan, path: Tuple[str, ...],
-                ndim: int) -> P:
-    """PartitionSpec for one parameter leaf, identified by its tree path.
+                shape: Tuple[int, ...]) -> P:
+    """PartitionSpec for one parameter leaf of ``shape``, identified by its
+    tree path.
 
     Stacked block params have a leading (n_blocks,) dim -> specs are shifted
     right by one (the stack dim is never sharded).
+
+    Placement rule for the fsdp axes: in a plan with no tensor parallelism
+    (``plan.tp_size == 1``) an fsdp-sharded matrix carries its shard on the
+    leading non-stack dim whenever that dim divides by the fsdp size, so
+    ``tok``, attention ``wo``, ``w_down`` and the recurrent mixers' output
+    projections are stored row-sharded like ``wq`` and ``w_up``.  The rule
+    exists for the gradient exchange: the TPU compiler fuses "all-reduce,
+    then keep my rows" into one all-reduce-scatter when the kept slice is
+    the leading dim, but on the last dim of a matrix at published widths
+    it runs a full all-reduce and each chip discards all but its slice.
+    Plans with tensor parallelism keep the layout below, where the model
+    axis owns those leading dims.  The rule only moves an fsdp shard; it
+    never shards a leaf the layout below leaves whole over the fsdp axes.
     """
     f, m = plan.fsdp, plan.tp
     names = [getattr(p, "key", getattr(p, "name", str(p))) for p in path]
@@ -208,10 +222,14 @@ def _param_spec(cfg: ModelConfig, plan: ParallelPlan, path: Tuple[str, ...],
     # per stage, exactly the slices core/pipeline.py's shard_map hands out
     pad = 1 if stacked else 0
     stack_entry = plan.pipe if (stacked and plan.pipe) else None
-    base_ndim = ndim - pad
+    base_ndim = len(shape) - pad
+    fsdp_leads = (bool(f) and plan.tp_size == 1 and base_ndim == 2
+                  and shape[pad] % plan.axis_size(f) == 0)
 
     def spec(*entries):
         entries = entries + (None,) * (base_ndim - len(entries))
+        if fsdp_leads and entries[-1] == f:
+            entries = (f, None)
         return P(*((stack_entry,) * pad + entries))
 
     in_attention = "mixer" in names
@@ -307,7 +325,7 @@ def _param_spec(cfg: ModelConfig, plan: ParallelPlan, path: Tuple[str, ...],
 def param_shardings(cfg: ModelConfig, plan: ParallelPlan, params_shape):
     """Tree of NamedShardings matching ``jax.eval_shape(init_params, ...)``."""
     def one(path, leaf):
-        spec = _param_spec(cfg, plan, path, len(leaf.shape))
+        spec = _param_spec(cfg, plan, path, leaf.shape)
         return fitted(plan, spec, leaf.shape)
     return jax.tree_util.tree_map_with_path(one, params_shape)
 
@@ -381,7 +399,7 @@ def make_param_gatherer(cfg: ModelConfig, plan: ParallelPlan):
 
     def gather(lp):
         def one(path, leaf):
-            spec = _param_spec(cfg, gplan, path, len(leaf.shape))
+            spec = _param_spec(cfg, gplan, path, leaf.shape)
             quant = (comm_dtype is not None and
                      jnp.issubdtype(leaf.dtype, jnp.floating))
             if quant:
@@ -418,7 +436,7 @@ def _normalize_spec(spec: P) -> P:
 
 
 def make_stage_param_spec_fn(cfg: ModelConfig, plan: ParallelPlan):
-    """(tree_path, ndim) -> PartitionSpec for pipeline *stage* param leaves.
+    """(tree_path, shape) -> PartitionSpec for pipeline *stage* param leaves.
 
     The stage shard_map (``core/pipeline.py``) computes over the full
     inner mesh: the stacked leaves shard their stack dim over the pipe
@@ -433,8 +451,8 @@ def make_stage_param_spec_fn(cfg: ModelConfig, plan: ParallelPlan):
     prefix = (_FakeKey(key="blocks"), _FakeKey(idx=0))
     head_tp = plan.attn == "head_tp"
 
-    def spec_fn(path, ndim):
-        sp = _param_spec(cfg, gplan, prefix + tuple(path), ndim)
+    def spec_fn(path, shape):
+        sp = _param_spec(cfg, gplan, prefix + tuple(path), shape)
         if not head_tp:
             # context plans keep stage params replicated over the model
             # axis (the sequence is sharded instead); strip the model

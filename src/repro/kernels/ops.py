@@ -62,9 +62,20 @@ def paged_decode_attention(q, k_pool, v_pool, tbl, ctx, *, n_splits=4,
                          interpret=_interp(interpret))
 
 
+# Largest (block_rows, d) rmsnorm block, in elements (512 rows of d 1024).
+# The kernels hold f32 copies of a block besides their double-buffered
+# operands: the backward at 512 bf16 rows of d 1536 asks for 18.1 MiB of
+# the 16 MiB of scoped VMEM a v5e kernel may take, whenever its operands
+# sit in HBM.
+_RMSNORM_BLOCK_ELEMS = 512 * 1024
+
+
 def rmsnorm(x, scale, *, eps=1e-6, block_rows=None, interpret="auto"):
     if block_rows is None:
         block_rows = _dtype_blocks(x.dtype, 256)
+        d = x.shape[-1]
+        while block_rows > 8 and block_rows * d > _RMSNORM_BLOCK_ELEMS:
+            block_rows //= 2
     return _rmsnorm(x, scale, eps=eps, block_rows=block_rows,
                     interpret=_interp(interpret))
 

@@ -433,7 +433,7 @@ def pipeline_stage_param_specs(rt: Runtime, stage_params):
     if rt.pipeline_param_spec_fn is None:
         return None
     return jax.tree_util.tree_map_with_path(
-        lambda pth, leaf: rt.pipeline_param_spec_fn(pth, leaf.ndim),
+        lambda pth, leaf: rt.pipeline_param_spec_fn(pth, leaf.shape),
         stage_params)
 
 

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import re
 from collections import defaultdict
-from typing import Dict
+from typing import Dict, List, Tuple
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -143,6 +143,28 @@ def collective_stats_flat(hlo_text: str) -> Dict[str, Dict[str, float]]:
             stats[m.group(2)]["bytes"] += _shape_bytes(m.group(1))
             stats[m.group(2)]["count"] += 1
     return dict(stats)
+
+
+def all_reduces(hlo_text: str) -> List[Tuple[str, List[Tuple[int, ...]]]]:
+    """-> [(computation, [dims of each result])] for every all-reduce of a
+    compiled HLO text.
+
+    Where it can, the TPU compiler fuses an all-reduce of which each
+    device keeps a slice into one reduce-scatter: that all-reduce sits in
+    a computation named ``all-reduce-scatter...``.  An all-reduce
+    anywhere else is a full one.
+    """
+    comps = _split_computations(hlo_text)
+    comps.pop("__entry__")
+    out = []
+    for comp, lines in comps.items():
+        for line in lines:
+            m = _COLL_RE.search(line)
+            if m and m.group(2) == "all-reduce" and "-done(" not in line:
+                shapes = _SHAPE_RE.findall(m.group(1))
+                out.append((comp, [tuple(int(d) for d in dims.split(",") if d)
+                                   for _, dims in shapes]))
+    return out
 
 
 def total_collective_bytes(hlo_text: str) -> int:

@@ -7,6 +7,7 @@ configuration from conftest."""
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro import strategy as strategy_lib
 from repro.configs import ShapeConfig, get_config, reduced
@@ -123,3 +124,51 @@ def test_sharded_train_equivalence(eight_devices, arch, attn_override):
 ])
 def test_sharded_decode_equivalence(eight_devices, arch):
     _check_decode(arch)
+
+
+# the tensor-parallel layout: the model axis owns the leading dim of the
+# output projections and of the vocabulary, the fsdp axis their last dim
+TP_SPECS = {
+    "['embed']['tok']": P("model", "data"),
+    "['blocks'][0]['mixer']['wq']": P(None, "data", "model"),
+    "['blocks'][0]['mixer']['wo']": P(None, "model", "data"),
+    "['blocks'][0]['ffn']['w_up']": P(None, "data", "model"),
+    "['blocks'][0]['ffn']['w_down']": P(None, "model", "data"),
+}
+
+
+def _fsdp4_specs(strategy):
+    """{leaf path: (shape, spec)} of ``param_shardings`` for qwen2 cut
+    small, planned by ``strategy`` over four host devices."""
+    cfg = reduced(get_config("qwen2-1.5b"), d_model=256)
+    shape = ShapeConfig("t", 64, 4, "train")
+    topo = strategy_lib.host_topology(n_devices=4)
+    strat, _ = strategy_lib.resolve(strategy, cfg, topo, shape)
+    plan = strat.to_plan(cfg, topo, shape)
+    pshapes = jax.eval_shape(lambda k: tfm.init_params(cfg, k),
+                             jax.random.PRNGKey(0))
+    pshard = par.param_shardings(cfg, plan, pshapes)
+    return {jax.tree_util.keystr(path): (leaf.shape, sharding.spec)
+            for (path, leaf), sharding in zip(
+                jax.tree_util.tree_leaves_with_path(pshapes),
+                jax.tree.leaves(pshard))}
+
+
+@pytest.mark.parametrize("strategy", ["fsdp_bf16", "fsdp_tp2_bf16"])
+def test_fsdp_shards_leading_dim_without_tp(eight_devices, strategy):
+    """With no tensor parallelism the fsdp axis shards the leading non-stack
+    dim of every weight matrix (``w_down``, ``wo`` and ``tok`` among them);
+    a tensor-parallel plan keeps the model axis on those leading dims."""
+    specs = _fsdp4_specs(strategy)
+    matrices = {k: (shape, spec) for k, (shape, spec) in specs.items()
+                if len(shape) - ("['blocks']" in k) == 2}
+    assert {"['embed']['tok']", "['blocks'][0]['mixer']['wo']",
+            "['blocks'][0]['ffn']['w_down']"} <= set(matrices)
+    if strategy == "fsdp_bf16":
+        for k, (shape, spec) in matrices.items():
+            lead = len(shape) - 2
+            assert spec[lead] == "data", (k, spec)
+            assert "data" not in spec[lead + 1:], (k, spec)
+    else:
+        for k, want in TP_SPECS.items():
+            assert specs[k][1] == want, (k, specs[k][1])

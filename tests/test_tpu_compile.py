@@ -6,11 +6,15 @@ chip at the widths the model configs publish.  The chip's compiler refuses
 block shapes that are not tiled to (8, 128), unsupported ops and kernels
 that overflow VMEM -- faults the interpret-mode tests in
 ``test_kernels.py`` cannot see.  Every case asserts that each of its named
-kernels survived as a ``tpu_custom_call`` in the compiled program.
+kernels survived as a ``tpu_custom_call`` in the compiled program.  One
+more test compiles a small fsdp train step over the whole slice and reads
+how the chip's compiler exchanges its weight gradients.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import dataclasses
+import math
 import os
 
 import jax
@@ -18,8 +22,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import strategy as strategy_lib
+from repro.configs import ShapeConfig, get_config, reduced
+from repro.core import compat
+from repro.core import parallel as par
 from repro.kernels import ops
-from repro.perf.hlo import pallas_kernels
+from repro.models import transformer as tfm
+from repro.optim import init_opt_state
+from repro.perf.hlo import all_reduces, pallas_kernels
+from repro.train.trainer import TrainConfig, jit_train_step, make_train_step
 
 DTYPES = [jnp.bfloat16, jnp.float32]
 
@@ -86,9 +97,11 @@ def _wkv6(r, k, v, w, u):
 
 
 # qwen3-0.6b: 16 query / 8 kv heads of 128, d_model 1024; rwkv6-1.6b:
-# 32 heads of 64.  Decode pools hold (blocks, kv heads, 16 tokens, 128).
+# 32 heads of 64; qwen2-1.5b: d_model 1536.  Decode pools hold (blocks, kv
+# heads, 16 tokens, 128).
 B, S, H, KV, D, DM = 1, 2048, 16, 8, 128, 1024
 Q, K, X = (B, S, H, D), (B, S, KV, D), (4, 512, DM)
+X_WIDE = (1, 1024, 1536)
 R = (1, 512, 32, 64)
 FWD, DQ, DKV = ("flash_attention_fwd", "flash_attention_bwd_dq",
                 "flash_attention_bwd_dkv")
@@ -105,6 +118,9 @@ CASES = {
     "rmsnorm_fwd_bwd": (_rmsnorm_fwd_bwd,
                         lambda dt: [(X, dt), ((DM,), F32), (X, dt)],
                         {"rmsnorm_fwd", "rmsnorm_bwd"}),
+    "rmsnorm_fwd_bwd_wide": (_rmsnorm_fwd_bwd, lambda dt: [
+        (X_WIDE, dt), ((X_WIDE[-1],), F32), (X_WIDE, dt)],
+        {"rmsnorm_fwd", "rmsnorm_bwd"}),
     "flash_decode": (_decode, lambda dt: [
         ((4, 1, H, D), dt), ((64, KV, 16, D), dt), ((64, KV, 16, D), dt),
         ((4, 16), jnp.int32), ((4,), jnp.int32)], {"flash_decode"}),
@@ -119,3 +135,46 @@ def test_kernel_compiles_for_v5e(case, dtype, one_chip):
     fn, shapes, kernels = CASES[case]
     found = pallas_kernels(_compile_text(fn, one_chip, *shapes(dtype)))
     assert kernels <= set(found), (case, found)
+
+
+def _fsdp4_step_text(cfg, topo):
+    """A small ``fsdp_bf16`` train step of ``cfg`` over the four chips of
+    the described slice -> its compiled text."""
+    shape = ShapeConfig("t", 128, 4, "train")
+    host = strategy_lib.host_topology(n_devices=4)
+    strat, _ = strategy_lib.resolve("fsdp_bf16", cfg, host, shape)
+    plan = strat.to_plan(cfg, host, shape, abstract=True)
+    mesh = compat.make_mesh(plan.mesh.axis_sizes, plan.mesh.axis_names,
+                            devices=topo.devices)
+    plan = dataclasses.replace(plan, mesh=mesh)
+    rt = par.make_runtime(cfg, plan, shape, remat=False)
+    pshapes = jax.eval_shape(lambda k: tfm.init_params(cfg, k),
+                             jax.random.PRNGKey(0))
+    oshapes = jax.eval_shape(init_opt_state, pshapes)
+    tok = jax.ShapeDtypeStruct((shape.global_batch, shape.seq_len),
+                               jnp.int32)
+    batch = {"tokens": tok, "labels": tok}
+    pshard = par.param_shardings(cfg, plan, pshapes)
+    oshard = {"m": pshard, "v": pshard, "step": par.fitted(plan, par.P(), ())}
+    with par.use_mesh(mesh):
+        step = jit_train_step(make_train_step(cfg, rt, TrainConfig()), pshard,
+                              oshard, par.batch_specs(cfg, plan, batch))
+        return step.lower(pshapes, oshapes, batch).compile().as_text()
+
+
+def test_fsdp4_weight_gradients_reduce_scatter(topo):
+    """Every weight matrix's gradient leaves a qwen2-shaped fsdp step over
+    four chips as a reduce-scatter.  A full all-reduce may carry only
+    vectors: biases and norm scales (at most one ``d_model`` vector a
+    layer), the loss and the gradient norm's partial sums."""
+    # at d_model 512 the compiler already runs a last-dim shard's gradient
+    # as a full all-reduce; at 256 it still reduce-scatters it
+    cfg = reduced(get_config("qwen2-1.5b"), d_model=512)
+    found = all_reduces(_fsdp4_step_text(cfg, topo))
+    scattered = [c for c, _ in found if c.startswith("all-reduce-scatter")]
+    full = [(c, dims) for c, results in found
+            if not c.startswith("all-reduce-scatter")
+            for dims in results
+            if math.prod(dims) > cfg.n_layers * cfg.d_model]
+    assert scattered, found
+    assert not full, full

@@ -6,7 +6,8 @@
 
 Everything is found by name.  The cell's entry in ``BENCHMARK.json``
 names its configuration (``configs/<config>.json``, the published
-config.json plus the program's registry name) and its traffic
+config.json plus the program's registry name and the module of its plain
+reference, ``<reference>.py``) and its traffic
 (``traffic/<traffic>.json``, which names the job kind,
 ``jobs/<kind>.py``); ``limits/<cell>.json`` holds the limits of the
 comparison that decides ``correct``; each per-layer metric is read by

@@ -43,6 +43,19 @@ def tiny_fsdp4():
 
 
 @pytest.fixture
+def tiny_pp2tp2():
+    """qwen2-1.5b's file and pp2tp2-train-s1024 at a CPU test's size, with
+    two key-value heads for the two chips of a stage to split."""
+    cfg = load("configs", "qwen2-1.5b.json")
+    cfg.update(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+               num_attention_heads=2, num_key_value_heads=2, vocab_size=256)
+    t = load("traffic", "pp2tp2-train-s1024.json")
+    t.update(name="tiny", seq_len=32, global_batch=4)
+    t["runtime"] = dict(t["runtime"], attn_impl="jnp", norm_impl="jnp")
+    return cfg, t
+
+
+@pytest.fixture
 def tiny_traffic():
     """train-s256x4 at seq 32 x batch 2 on the jnp attention and norms
     (the Pallas kernels run in interpret mode on a CPU, too slowly here)."""

@@ -8,7 +8,11 @@ the step it just dispatched: it waits only for the step ``DEPTH`` before,
 so that the host cannot run more than ``DEPTH`` steps ahead of the chip
 and the window ends within a few steps of its deadline.
 
-The weights are the benchmark's (``reference.init_weights`` in the
+The configuration file names its reference module (``"reference"``,
+found by ``reference_base.for_config``), which builds the program's
+configuration from the file and holds the layer equations, the weights'
+layout and initialisation and the model's counts of operations.  The
+weights are the benchmark's (the reference's ``init_weights`` in the
 program's sharding, one jitted call); the optimizer state, the step, the
 plan and the batches are the program's.  After the window the program's
 state is freed and the plain reference repeats the checked steps.
@@ -29,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chip import flops, reference
+from chip import reference_base as base
 
 DEPTH = 2                 # steps the host may run ahead of the chip
 TRACE_SECONDS = 3.0       # the traced part at the end of a --trace 1 window
@@ -39,30 +43,6 @@ KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-def program_config(config: dict):
-    """The program's ModelConfig for a published config.json, with every
-    size and option set as the file states -> (cfg, fields changed from
-    the program's registry entry)."""
-    from repro.configs import get_config
-    base = get_config(config["registry"])
-    s = reference.sizes(config)
-    want = dict(n_layers=s["layers"], d_model=s["d"], n_heads=s["h"],
-                n_kv_heads=s["kv"], d_ff=s["ff"], vocab_size=s["vocab"],
-                norm_eps=s["eps"], rope_theta=s["theta"],
-                tie_embeddings=True, qkv_bias=s["qkv_bias"],
-                qk_norm=s["qk_norm"], sliding_window=0, act="silu",
-                glu=True, norm="rmsnorm", mixer="attn", rope="rope",
-                attn_logit_softcap=0.0, pos_embed="none")
-    if base.head_dim_ != s["hd"]:
-        want["head_dim"] = s["hd"]
-    if base.moe.n_experts:
-        raise ValueError(f"{base.name} has experts; the train job's "
-                         "reference is dense")
-    changed = {k: (getattr(base, k), v) for k, v in want.items()
-               if getattr(base, k) != v}
-    return dataclasses.replace(base, **want), changed
 
 
 def _shape_tree(tree):
@@ -92,7 +72,8 @@ class TrainJob:
         from repro.train.trainer import TrainConfig, make_train_step
 
         self.config, self.traffic, self.chips = config, traffic, chips
-        self.cfg, self.changed = program_config(config)
+        self.model = base.for_config(config)
+        self.cfg, self.changed = self.model.program_config(config)
         self.seq, self.batch = traffic["seq_len"], traffic["global_batch"]
         self.tokens_per_step = self.seq * self.batch
         topo = strategy_lib.host_topology(n_devices=chips)
@@ -119,7 +100,7 @@ class TrainJob:
         from repro.models import transformer as tfm
         pshapes = jax.eval_shape(lambda k: tfm.init_params(self.cfg, k),
                                  jax.random.PRNGKey(0))
-        want = reference.weight_shapes(config)
+        want = self.model.weight_shapes(config)
         if _shape_tree(pshapes) != jax.tree.map(
                 tuple, want, is_leaf=lambda x: isinstance(x, tuple)):
             raise ValueError("the program's parameter layout is not the "
@@ -127,7 +108,10 @@ class TrainJob:
         self.pshard = par.param_shardings(self.cfg, self.plan, pshapes)
         self.oshard = {"m": self.pshard, "v": self.pshard,
                        "step": par.fitted(self.plan, par.P(), ())}
-        if fault == "no_exchange":
+        if fault == "no_exchange" and self.rt.pipeline_axis:
+            self.step_fn = no_tp_exchange(make_train_step(self.cfg, self.rt,
+                                                          self.tc))
+        elif fault == "no_exchange":
             # the step inside a shard_map, where the plan's constraints and
             # kernel sharding do not apply
             local = dataclasses.replace(self.rt, constrain=None,
@@ -139,7 +123,7 @@ class TrainJob:
                                                  self.tc), fault)
         from repro.optim import init_opt_state
         self.make_weights = jax.jit(
-            functools.partial(reference.init_weights, config),
+            functools.partial(self.model.init_weights, config),
             out_shardings=self.pshard)
         self.init_opt = jax.jit(init_opt_state, out_shardings=self.oshard)
         self.compiled = None
@@ -177,7 +161,7 @@ class TrainJob:
         """The benchmark's weights in the program's sharding, and the
         program's optimizer state -> (params, opt_state)."""
         with self.par.use_mesh(self.plan.mesh):
-            params = self.make_weights(reference.seed_key(seed))
+            params = self.make_weights(base.seed_key(seed))
             return params, self.init_opt(params)
 
     def compile(self, params, opt_state, batch):
@@ -193,11 +177,11 @@ class TrainJob:
         self.memory = self.compiled.memory_analysis()
         b1 = self.traffic["optimizer"]["b1"]
         # m after one step is (1 - b1) times the clipped gradient
-        self.grad_norms = jax.jit(lambda o: reference.leaf_norms(
+        self.grad_norms = jax.jit(lambda o: base.leaf_norms(
             jax.tree.map(lambda m: m / (1 - b1), o["m"])))
-        self.update_norms = jax.jit(lambda p, k: reference.leaf_norms(
+        self.update_norms = jax.jit(lambda p, k: base.leaf_norms(
             jax.tree.map(jnp.subtract, p,
-                         reference.init_weights(self.config, k))))
+                         self.model.init_weights(self.config, k))))
 
     # -- the checked steps -------------------------------------------------
 
@@ -206,7 +190,7 @@ class TrainJob:
         step -> (params, opt_state, next batch, batch iterator, readings,
         corpus)."""
         n = self.traffic["check_steps"]
-        key = reference.seed_key(seed)
+        key = base.seed_key(seed)
         params, opt_state = self.init_state(seed)
         corpus = self.corpus(seed)
         it = self.batches(corpus)
@@ -240,16 +224,16 @@ class TrainJob:
                 mesh = Mesh(np.array(jax.devices()[:self.chips]), ("x",))
                 w_sh = jax.tree.map(
                     lambda s: _spread(mesh, s),
-                    reference.weight_shapes(self.config),
+                    self.model.weight_shapes(self.config),
                     is_leaf=lambda x: isinstance(x, tuple))
                 rows = P("x") if self.batch % self.chips == 0 else P()
                 b_sh = (NamedSharding(mesh, rows), NamedSharding(mesh, rows))
                 shardings = (w_sh, b_sh)
-            self.ref = reference.Reference(
+            self.ref = self.model.Reference(
                 self.config, self.traffic["optimizer"],
                 self.traffic["schedule"], shardings)
-        rows = reference.rows(corpus, self.seq, self.batch,
-                              self.traffic["check_steps"])
+        rows = base.rows(corpus, self.seq, self.batch,
+                         self.traffic["check_steps"])
         return self.ref.run(seed, rows, dot_dtype=dot_dtype, fault=fault)
 
     # -- the window --------------------------------------------------------
@@ -376,6 +360,24 @@ def no_exchange(step_fn, job):
     return step
 
 
+def no_tp_exchange(step_fn):
+    """The pipelined step with the exchange between the chips of a stage
+    left out: the tensor-parallel all-reduce of each layer's attention and
+    FFN output (``tp_reduce_out``) is skipped while the step is traced, so
+    each chip adds only its own heads' and hidden units' part to the
+    residual stream."""
+    from repro.models import transformer as tfm
+
+    def step(params, opt_state, batch):
+        reduce_out = tfm.tp_reduce_out
+        tfm.tp_reduce_out = lambda x, rt: x
+        try:
+            return step_fn(params, opt_state, batch)
+        finally:
+            tfm.tp_reduce_out = reduce_out
+    return step
+
+
 def memory_peak(devices) -> Optional[int]:
     """The allocator's peak bytes in use on the fullest chip; each chip's
     whole memory statistics go to an earlier line."""
@@ -431,13 +433,53 @@ def run(ctx) -> dict:
     want = job.reference(ctx.seed, corpus)
     log(f"[check] reference losses {want['losses']} "
         f"({time.perf_counter() - t_ref:.1f} s)")
-    rec["compare"] = reference.compare(got, want)
-    rec["flops_per_token"] = flops.train_flops_per_token(ctx.config,
-                                                         job.seq)
-    rec["attention_cost"] = flops.flash_attention_cost(
+    rec["compare"] = base.compare(got, want)
+    rec["flops_per_token"] = job.model.train_flops_per_token(ctx.config,
+                                                             job.seq)
+    rec["attention_cost"] = job.model.flash_attention_cost(
         ctx.config, job.seq, job.batch)
     rec["tokens_per_step"] = job.tokens_per_step
     rec["e2e"] = {"train_tokens_per_s": rec["tokens_per_s_chip"],
                   "setup_s": rec["setup_s"]}
     rec["attempted"] = rec["steps"]
     return rec
+
+
+def calibrate(config: dict, traffic: dict, chips: int, seeds, control):
+    """The readings a training cell's limits are set from, one row each:
+    for every seed the program's checked steps against the reference
+    (``program``, the lower readings); for each seed of ``control`` the
+    control, the reference with every matrix product in float8_e4m3fn put
+    in the program's place (``control_fp8``), half the batch left out of
+    the reference put in its place (``fault_half_batch``) and, on several
+    chips, the program with the exchange between chips left out
+    (``fault_no_exchange``): the upper readings.  A state left unchanged
+    reads 1 by construction and needs no run."""
+    job = TrainJob(config, traffic, chips)
+    faulty = TrainJob(config, traffic, chips, "no_exchange") \
+        if chips > 1 and control else None
+
+    def row(kind, seed, got, want):
+        gaps = base.compare(got, want)
+        keys = ("loss_gap", "grad_norm_gap", "update_norm_gap")
+        return {"kind": kind, "seed": seed,
+                **{k: gaps[k][0] for k in keys},
+                "at": {k: gaps[k][1] for k in keys},
+                "left_out": gaps["left_out"],
+                "losses": got["losses"], "ref_losses": want["losses"]}
+
+    for seed in seeds:
+        params, opt_state, batch, it, got, corpus = job.check_steps(seed)
+        del params, opt_state, batch, it
+        want = job.reference(seed, corpus)
+        yield row("program", seed, got, want)
+        if seed not in control:
+            continue
+        yield row("control_fp8", seed, job.reference(
+            seed, corpus, dot_dtype="float8_e4m3fn"), want)
+        yield row("fault_half_batch", seed,
+                  job.reference(seed, corpus, fault="half_batch"), want)
+        if faulty is not None:
+            params, opt_state, batch, it, got, _ = faulty.check_steps(seed)
+            del params, opt_state, batch, it
+            yield row("fault_no_exchange", seed, got, want)

@@ -1,7 +1,8 @@
 """Share of its roofline that the flash-attention kernels reach: the least
-time the chip could take for the FLOPs and bytes the kernels need
-(``flops.flash_attention_cost``, from shapes) over their measured device
-time per step and chip.  The bound (compute or memory) is logged."""
+time the chip could take for the FLOPs and bytes the kernels need (the
+reference module's ``flash_attention_cost``, from shapes) over their
+measured device time per step and chip.  The bound (compute or memory)
+is logged."""
 
 from chip import flops, trace
 

@@ -1,7 +1,7 @@
 """Model FLOP/s utilization of the traced window: FLOPs a token needs
-(``flops.train_flops_per_token``, no recomputation) x tokens per second
-per chip over the traced window (host clock, ended by a sync) / the
-chip's bf16 peak from ``peaks.json``."""
+(the configuration's reference module's ``train_flops_per_token``, no
+recomputation) x tokens per second per chip over the traced window (host
+clock, ended by a sync) / the chip's bf16 peak from ``peaks.json``."""
 
 
 def read(run):

@@ -57,6 +57,8 @@ def test_cell_files_resolve(cell):
     b = _bench()
     files = bench.cell_files(b, cell)
     assert os.path.exists(files["job"])
+    from chip.reference_base import for_config
+    for_config(files["config"])
     from repro.configs import get_config
     get_config(files["config"]["registry"])
     lim = files["limits"]["limits"]
@@ -71,13 +73,26 @@ def test_cell_files_resolve(cell):
     f[:-5] for f in os.listdir(os.path.join(HERE, "configs"))))
 def test_config_files_follow_their_source(name):
     """Published sizes unchanged unless listed in ``reduced``, and a
-    program configuration built from them."""
-    from chip.jobs.train import program_config
+    program configuration built from them by the configuration's own
+    reference module: every size the program reads back equals the file's;
+    each key in ``reduced`` states its published value beside the cut,
+    and a cut file states the deployment it stands for."""
+    from chip.reference_base import for_config
     cfg = load("configs", name + ".json")
-    prog, _ = program_config(cfg)
-    assert prog.n_layers == cfg["num_hidden_layers"]
-    assert prog.d_model == cfg["hidden_size"]
-    assert cfg["reduced"] == []
+    model = for_config(cfg)
+    prog, _ = model.program_config(cfg)
+    values = model.program_values(prog)
+    assert {"num_hidden_layers", "hidden_size"} <= set(values)
+    for key, value in values.items():
+        if key in cfg:
+            assert value == cfg[key], (key, value, cfg[key])
+    published = cfg.get("published", {})
+    for key in cfg["reduced"]:
+        assert key in cfg and key in published, key
+        assert published[key] != cfg[key], key
+    assert set(published) <= set(cfg["reduced"])
+    if cfg["reduced"]:
+        assert cfg.get("deployment")
     entries = {c["name"]: c for c in _bench()["configs"]}
     if name not in entries:       # a configuration no cell runs yet
         return

@@ -10,6 +10,7 @@ import pytest
 
 from chip import bench, flops
 from chip.conftest import HERE, ROOT, load
+from chip.reference_base import for_config
 
 MATMULS = ("wq", "wk", "wv", "wo", "w_up", "w_gate", "w_down", "tok")
 
@@ -20,24 +21,25 @@ def test_matmul_params_match_the_program_layout(name, published):
     """N from the config's sizes equals the count of the program's own
     projection and (tied) embedding weights."""
     from repro.models import transformer as tfm
-    from chip.jobs.train import program_config
     config = load("configs", name + ".json")
-    cfg, _ = program_config(config)
+    model = for_config(config)
+    cfg, _ = model.program_config(config)
     shapes = jax.eval_shape(lambda k: tfm.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     flat = jax.tree_util.tree_flatten_with_path(shapes)[0]
     n = sum(leaf.size for path, leaf in flat
             if getattr(path[-1], "key", "") in MATMULS)
-    assert flops.matmul_params(config) == n
+    assert model.matmul_params(config) == n
     assert abs(n - published) / published < 0.01
 
 
 def test_train_flops_per_token_counts_causal_attention():
     config = load("configs", "qwen3-0.6b.json")
-    n = flops.matmul_params(config)
+    model = for_config(config)
+    n = model.matmul_params(config)
     att = 12 * 28 * (1024 + 1) / 2 * 16 * 128
-    assert flops.train_flops_per_token(config, 1024) == 6 * n + att
-    assert 3.9e9 < flops.train_flops_per_token(config, 1024) < 3.95e9
+    assert model.train_flops_per_token(config, 1024) == 6 * n + att
+    assert 3.9e9 < model.train_flops_per_token(config, 1024) < 3.95e9
 
 
 def test_roofline_names_its_bound():

@@ -5,7 +5,7 @@ import jax
 import numpy as np
 import pytest
 
-from chip import reference
+from chip import reference_base as base
 from chip.jobs.train import TrainJob
 
 
@@ -18,7 +18,7 @@ def test_reference_matches_the_program_step_in_float32(f32_job):
     seed = 2**31 + 5
     *_, got, corpus = f32_job.check_steps(seed)
     want = f32_job.reference(seed, corpus)
-    gaps = reference.compare(got, want)
+    gaps = base.compare(got, want)
     assert gaps["loss_gap"][0] < 2e-5, gaps
     assert gaps["grad_norm_gap"][0] < 1e-4, gaps
     assert gaps["update_norm_gap"][0] < 1e-4, gaps
@@ -32,16 +32,17 @@ def test_reference_matches_fsdp_over_four_devices_in_float32(tiny_fsdp4):
     job = TrainJob(config, dict(traffic, strategy="fsdp_f32"), 4)
     seed = 2**31 + 3
     *_, got, corpus = job.check_steps(seed)
-    gaps = reference.compare(got, job.reference(seed, corpus))
+    gaps = base.compare(got, job.reference(seed, corpus))
     assert gaps["loss_gap"][0] < 2e-5, gaps
     assert gaps["grad_norm_gap"][0] < 1e-4, gaps
     assert gaps["update_norm_gap"][0] < 1e-4, gaps
 
 
 def test_weights_come_from_the_seed(tiny_config):
-    a = reference.init_weights(tiny_config, reference.seed_key(2**33 + 1))
-    b = reference.init_weights(tiny_config, reference.seed_key(2**33 + 1))
-    c = reference.init_weights(tiny_config, reference.seed_key(1))
+    init = base.for_config(tiny_config).init_weights
+    a = init(tiny_config, base.seed_key(2**33 + 1))
+    b = init(tiny_config, base.seed_key(2**33 + 1))
+    c = init(tiny_config, base.seed_key(1))
     la, lb, lc = (jax.tree.leaves(x) for x in (a, b, c))
     assert all(np.array_equal(x, y) for x, y in zip(la, lb))
     assert not np.array_equal(a["embed"]["tok"], c["embed"]["tok"])
@@ -56,7 +57,7 @@ def test_reference_rows_match_the_program_batcher(f32_job):
     assert not np.array_equal(corpus, f32_job.corpus(seed + 1))
     n = len(corpus) // (f32_job.seq * f32_job.batch) + 2   # past the wrap
     it = f32_job.batches(corpus)
-    for tokens, targets in reference.rows(corpus, f32_job.seq,
+    for tokens, targets in base.rows(corpus, f32_job.seq,
                                           f32_job.batch, n):
         b = next(it)
         assert np.array_equal(b["tokens"], tokens)
@@ -70,15 +71,15 @@ def test_non_finite_program_numbers_read_as_infinite_gaps():
     got = {"losses": [float("nan")],
            "grad_norms": {"a": float("nan"), "b": 2.0},
            "update_norms": {"a": 1.0, "b": float("inf")}}
-    gaps = reference.compare(got, want)
+    gaps = base.compare(got, want)
     assert all(gaps[k][0] == float("inf")
                for k in ("loss_gap", "grad_norm_gap", "update_norm_gap"))
 
 
 def test_narrow_dot_rounds_operands():
     x = jax.random.normal(jax.random.PRNGKey(0), (64, 64))
-    exact = reference.make_dot()("ij,jk->ik", x, x)
-    fp8 = reference.make_dot("float8_e4m3fn")("ij,jk->ik", x, x)
+    exact = base.make_dot()("ij,jk->ik", x, x)
+    fp8 = base.make_dot("float8_e4m3fn")("ij,jk->ik", x, x)
     rel = float(np.abs(fp8 - exact).max() / np.abs(exact).max())
     assert 1e-3 < rel < 0.2
 
@@ -95,6 +96,6 @@ def test_control_in_fp8_is_not_correct(cell, tiny_config, tiny_traffic):
         corpus = job.corpus(seed)
         want = job.reference(seed, corpus)
         got = job.reference(seed, corpus, dot_dtype="float8_e4m3fn")
-        rec = {"compare": reference.compare(got, want), "failed": 0}
+        rec = {"compare": base.compare(got, want), "failed": 0}
         correct, checks = bench.verdict(rec, load("limits", cell + ".json"))
         assert not correct, checks

@@ -1,5 +1,6 @@
 """Architecture registry: ``get_config(arch_id)`` / ``list_archs()``."""
-from repro.configs.base import ModelConfig, MoEConfig, MambaConfig, ShapeConfig, SHAPES, reduced
+from repro.configs.base import (ModelConfig, MoEConfig, MambaConfig,
+                                ShapeConfig, SHAPES, YarnConfig, reduced)
 
 from repro.configs.rwkv6_1p6b import CONFIG as _rwkv6
 from repro.configs.deepseek_moe_16b import CONFIG as _deepseek
@@ -12,6 +13,7 @@ from repro.configs.qwen3_0p6b import CONFIG as _qwen3
 from repro.configs.dbrx_132b import CONFIG as _dbrx
 from repro.configs.h2o_danube_1p8b import CONFIG as _danube
 from repro.configs.llama2 import CONFIGS as _llama2
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 
 ASSIGNED = {
     c.name: c for c in (
@@ -21,6 +23,7 @@ ASSIGNED = {
 
 REGISTRY = dict(ASSIGNED)
 REGISTRY.update(_llama2)
+REGISTRY[_deepseek_v2_lite.name] = _deepseek_v2_lite
 
 
 def get_config(name: str) -> ModelConfig:
@@ -44,6 +47,7 @@ def supports_shape(cfg: ModelConfig, shape: ShapeConfig) -> bool:
 
 __all__ = [
     "ModelConfig", "MoEConfig", "MambaConfig", "ShapeConfig", "SHAPES",
+    "YarnConfig",
     "reduced", "ASSIGNED", "REGISTRY", "get_config", "list_archs",
     "supports_shape",
 ]

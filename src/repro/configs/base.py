@@ -7,6 +7,7 @@ shapes are ``ShapeConfig`` instances in ``SHAPES``.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 
@@ -22,6 +23,30 @@ class MoEConfig:
     # which layers are MoE: layer i is MoE iff i >= start and (i - start) % every == 0
     moe_start_layer: int = 0
     moe_every: int = 1
+    # renormalise the top-k router weights to sum to 1 (DeepSeek-V2: no)
+    norm_topk_prob: bool = True
+    # the expert share this layer holds: routed experts held_offset ..
+    # held_offset + held_experts - 1 of the n_experts the router scores
+    # (0 -> all of them); the others live on further chips
+    held_experts: int = 0
+    held_offset: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv 2309.00071) as DeepSeek-V2 configures it;
+    factor 0 -> plain rope.  cos/sin are not rescaled, as DeepSeek-V2's
+    m(mscale) / m(mscale_all_dim) is 1 where the two are equal."""
+    factor: float = 0.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,6 +74,14 @@ class ModelConfig:
     qk_norm: bool = False
     sliding_window: int = 0         # 0 -> full causal attention
     attn_logit_softcap: float = 0.0
+    # latent attention (DeepSeek-V2 MLA, no q compression): keys and
+    # values come up from a kv_lora_rank-wide latent; q/k heads are
+    # qk_nope_head_dim + qk_rope_head_dim wide, values v_head_dim.
+    # kv_lora_rank 0 -> plain attention
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # mixer layout: 'attn' | 'rwkv6' | 'mamba'; hybrids interleave.
     mixer: str = "attn"
@@ -58,6 +91,7 @@ class ModelConfig:
     # position information
     rope: str = "rope"              # 'rope' | 'mrope' | 'none'
     rope_theta: float = 10000.0
+    yarn: YarnConfig = dataclasses.field(default_factory=YarnConfig)
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     pos_embed: str = "none"         # 'none' | 'sinusoidal' (musicgen)
 
@@ -93,6 +127,28 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of the rotated part of a q/k head."""
+        return self.qk_rope_head_dim if self.mla else self.head_dim_
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale: 1/sqrt(q/k head width), times YaRN's m^2 where
+        mscale_all_dim is set (DeepSeek-V2)."""
+        if not self.mla:
+            return self.head_dim_ ** -0.5
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        y = self.yarn
+        if y.factor > 1 and y.mscale_all_dim:
+            m = 0.1 * y.mscale_all_dim * math.log(y.factor) + 1.0
+            scale *= m * m
+        return scale
+
+    @property
     def rwkv_heads(self) -> int:
         return self.d_model // self.rwkv_head_dim
 
@@ -121,7 +177,14 @@ class ModelConfig:
         for i in range(self.n_layers):
             kind = self.layer_kind(i)
             total += d                          # pre-mixer norm
-            if kind == "attn":
+            if kind == "attn" and self.mla:
+                h, r = self.n_heads, self.kv_lora_rank
+                qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+                total += d * h * qk                          # wq
+                total += d * (r + self.qk_rope_head_dim) + r  # wkv_a, norm
+                total += r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                total += h * self.v_head_dim * d             # wo
+            elif kind == "attn":
                 q = d * self.n_heads * hd
                 kv = 2 * d * self.kv_heads * hd
                 o = self.n_heads * hd * d
@@ -147,7 +210,7 @@ class ModelConfig:
             mult = 3 if self.glu else 2
             if self.is_moe_layer(i):
                 m = self.moe
-                total += m.n_experts * mult * d * m.expert_d_ff
+                total += m.n_held * mult * d * m.expert_d_ff
                 total += m.n_shared_experts * mult * d * m.expert_d_ff
                 total += d * m.n_experts        # router
             else:
@@ -159,14 +222,17 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Params active per token (MoE: only top-k + shared experts)."""
+        """Params active per token (MoE: only top-k + shared experts; of a
+        held share, the top_k * held / n_experts experts a token meets
+        there on average), rounded to a whole count."""
         if self.moe.n_experts == 0:
             return self.param_count()
         m = self.moe
         mult = 3 if self.glu else 2
-        inactive_experts = m.n_experts - m.top_k
+        inactive_experts = m.n_held - m.top_k * m.n_held / m.n_experts
         n_moe_layers = sum(self.is_moe_layer(i) for i in range(self.n_layers))
-        return self.param_count() - n_moe_layers * inactive_experts * mult * self.d_model * m.expert_d_ff
+        return round(self.param_count() - n_moe_layers * inactive_experts
+                     * mult * self.d_model * m.expert_d_ff)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -265,6 +265,10 @@ def _param_spec(cfg: ModelConfig, plan: ParallelPlan, path: Tuple[str, ...],
                 return spec(f, head_m)
             if leaf in ("wk", "wv"):
                 return spec(f, kv_m)
+            if leaf == "wkv_a":     # latent attention: x -> (latent, k_pe)
+                return spec(f, None)
+            if leaf == "wkv_b":     # latent -> per-head (k_nope, v)
+                return spec(None, head_m)
             if leaf == "wo":
                 return spec(head_m, f)
             if leaf == "bq":

@@ -23,6 +23,10 @@ TPU-native design (not a CUDA port, see DESIGN.md §2):
     transpose the row to the (bq, 1) column the softmax math needs.
   * GQA: q heads are grouped by kv head via index_map arithmetic — no
     repeated K/V in HBM.
+  * the value head may be narrower or wider than the q/k head (latent
+    attention: q/k 192 = 128 + 64 rotated, v 128), and the softmax scale
+    may be given (YaRN's m^2 on top of 1/sqrt(D)); each operand is tiled
+    at its own width, so no head is padded.
   * causal + sliding-window masks built from absolute block offsets with
     broadcasted iota (2D, as the TPU requires).
 
@@ -117,7 +121,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q = q_ref[0].astype(jnp.float32)                    # (bq, D)
     k = k_ref[0].astype(jnp.float32)                    # (bk, D)
     v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)                  # (bq, D)
+    do = do_ref[0].astype(jnp.float32)                  # (bq, Dv)
     lse = lse_ref[0].T                                  # (bq, 1)
     delta = delta_ref[0].T                              # (bq, 1)
 
@@ -153,7 +157,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q = q_ref[0].astype(jnp.float32)                    # (bq, D)
     k = k_ref[0].astype(jnp.float32)                    # (bk, D)
     v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)                  # (bq, D)
+    do = do_ref[0].astype(jnp.float32)                  # (bq, Dv)
     lse = lse_ref[0].T                                  # (bq, 1)
     delta = delta_ref[0].T                              # (bq, 1)
 
@@ -176,6 +180,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 
 def _dims(q_shape, k_shape, block_q, block_kv):
+    """-> (B, S, H, D, Kv, G, bq, bk, Sp); D is the q/k head width."""
     B, S, H, D = q_shape
     Kv = k_shape[2]
     assert H % Kv == 0, (H, Kv)
@@ -207,24 +212,27 @@ def _ungroup_q(x, B, Kv, G, S):
 
 
 def _group(q, k, v, B, Sp, H, Kv, G, D, S):
-    """(B, S, H|Kv, D) -> (B*Kv*G | B*Kv, Sp, D), q heads grouped by kv head."""
+    """(B, S, H|Kv, D|Dv) -> (B*Kv*G | B*Kv, Sp, D|Dv), q heads grouped by
+    kv head."""
     qg = _group_q(q, Kv, G, Sp)
     if Sp != S:
         pad = [(0, 0), (0, Sp - S), (0, 0), (0, 0)]
         k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     kg = k.transpose(0, 2, 1, 3).reshape(B * Kv, Sp, D)
-    vg = v.transpose(0, 2, 1, 3).reshape(B * Kv, Sp, D)
+    vg = v.transpose(0, 2, 1, 3).reshape(B * Kv, Sp, v.shape[-1])
     return qg, kg, vg
 
 
-def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret):
-    """-> (out (B,S,H,D), residuals for the backward)."""
+def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret,
+                   scale):
+    """-> (out (B,S,H,Dv), residuals for the backward)."""
     B, S, H, D, Kv, G, bq, bk, Sp = _dims(q.shape, k.shape, block_q, block_kv)
+    Dv = v.shape[-1]
     nq, nk = Sp // bq, Sp // bk
     qg, kg, vg = _group(q, k, v, B, Sp, H, Kv, G, D, S)
 
     kernel = functools.partial(
-        _flash_kernel, scale=D ** -0.5, block_q=bq, block_kv=bk,
+        _flash_kernel, scale=_scale(scale, D), block_q=bq, block_kv=bk,
         n_kv=nk, causal=causal, window=window, seq_len=S)
 
     out, lse = pl.pallas_call(
@@ -233,20 +241,20 @@ def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret):
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j, G=G: (b // G, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * Kv * G, Sp, D), q.dtype),
+            jax.ShapeDtypeStruct((B * Kv * G, Sp, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * Kv * G, 1, Sp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention_fwd",
@@ -257,11 +265,13 @@ def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret):
     return _ungroup_q(out, B, Kv, G, S), (qg, kg, vg, out, lse)
 
 
-def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
+def _flash_backward(causal, window, block_q, block_kv, interpret, scale,
+                    res, g):
     qg, kg, vg, og, lse = res                  # all grouped+padded by the fwd
-    B, S, H, D = g.shape
+    B, S, H, Dv = g.shape
+    D = qg.shape[-1]
     Kv = kg.shape[0] // B
-    _, _, _, _, _, G, bq, bk, Sp = _dims(g.shape, (B, S, Kv, D),
+    _, _, _, _, _, G, bq, bk, Sp = _dims((B, S, H, D), (B, S, Kv, D),
                                          block_q, block_kv)
     nq, nk = Sp // bq, Sp // bk
     dog = _group_q(g, Kv, G, Sp)
@@ -270,7 +280,7 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
     delta = jnp.sum(dog.astype(jnp.float32) * og.astype(jnp.float32),
                     axis=-1)[:, None, :]                # (N, 1, Sp) like lse
 
-    scale = D ** -0.5
+    scale = _scale(scale, D)
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, block_q=bq, block_kv=bk,
         n_kv=nk, causal=causal, window=window, seq_len=S)
@@ -280,8 +290,8 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j, G=G: (b // G, j, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
@@ -304,8 +314,8 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
             pl.BlockSpec((1, bq, D),
                          lambda b, j, t, G=G, nq=nq: (b * G + t // nq, t % nq, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bq, D),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bq, Dv),
                          lambda b, j, t, G=G, nq=nq: (b * G + t // nq, t % nq, 0)),
             pl.BlockSpec((1, 1, bq),
                          lambda b, j, t, G=G, nq=nq: (b * G + t // nq, 0, t % nq)),
@@ -314,45 +324,53 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, res, g):
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, t: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Kv, Sp, D), kg.dtype),
-            jax.ShapeDtypeStruct((B * Kv, Sp, D), vg.dtype),
+            jax.ShapeDtypeStruct((B * Kv, Sp, Dv), vg.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
     )(qg, kg, vg, dog, lse, delta)
 
     dq = _ungroup_q(dq, B, Kv, G, S)
     dk = dk.reshape(B, Kv, Sp, D).transpose(0, 2, 1, 3)[:, :S]
-    dv = dv.reshape(B, Kv, Sp, D).transpose(0, 2, 1, 3)[:, :S]
+    dv = dv.reshape(B, Kv, Sp, Dv).transpose(0, 2, 1, 3)[:, :S]
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, window, block_q, block_kv, interpret):
+def _scale(scale, D):
+    """The softmax scale: as given, else 1/sqrt(q/k head width)."""
+    return D ** -0.5 if scale is None else scale
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, block_q, block_kv, interpret, scale):
     out, _ = _flash_forward(q, k, v, causal, window, block_q, block_kv,
-                            interpret)
+                            interpret, scale)
     return out
 
 
-def _flash_fwd_rule(q, k, v, causal, window, block_q, block_kv, interpret):
+def _flash_fwd_rule(q, k, v, causal, window, block_q, block_kv, interpret,
+                    scale):
     return _flash_forward(q, k, v, causal, window, block_q, block_kv,
-                          interpret)
+                          interpret, scale)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_backward)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_kv", "interpret"))
+    "causal", "window", "block_q", "block_kv", "interpret", "scale"))
 def flash_attention(q, k, v, *, causal=True, window=0,
-                    block_q=128, block_kv=256, interpret=False):
-    """q (B,S,H,D), k/v (B,S,Kv,D) -> (B,S,H,D). Self-attention layout.
+                    block_q=128, block_kv=256, interpret=False, scale=None):
+    """q (B,S,H,D), k (B,S,Kv,D), v (B,S,Kv,Dv) -> (B,S,H,Dv).
+    Self-attention layout; ``scale`` defaults to D ** -0.5.
 
     Differentiable: ``jax.grad`` runs the Pallas FA2 backward kernels.
     """
-    return _flash(q, k, v, causal, window, block_q, block_kv, interpret)
+    return _flash(q, k, v, causal, window, block_q, block_kv, interpret,
+                  scale)

@@ -11,14 +11,16 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def attention_ref(q, k, v, *, causal=True, window=0):
-    """q (B,Sq,H,D), k/v (B,Skv,Kv,D) -> (B,Sq,H,D); fp32 softmax."""
+def attention_ref(q, k, v, *, causal=True, window=0, scale=None):
+    """q (B,Sq,H,D), k (B,Skv,Kv,D), v (B,Skv,Kv,Dv) -> (B,Sq,H,Dv); fp32
+    softmax; ``scale`` defaults to D ** -0.5."""
     B, Sq, H, D = q.shape
     Kv = k.shape[2]
     G = H // Kv
     qg = q.reshape(B, Sq, Kv, G, D)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (D ** -0.5)
+                   k.astype(jnp.float32)) * (D ** -0.5 if scale is None
+                                             else scale)
     q_pos = jnp.arange(Sq)
     k_pos = jnp.arange(k.shape[1])
     mask = jnp.ones((Sq, k.shape[1]), bool)
@@ -29,7 +31,7 @@ def attention_ref(q, k, v, *, causal=True, window=0):
     s = jnp.where(mask[None, None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", w, v.astype(jnp.float32))
-    return out.reshape(B, Sq, H, D).astype(q.dtype)
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def paged_attention_ref(q, k_pool, v_pool, tbl, ctx, *, window=0):

@@ -1,4 +1,5 @@
-"""Attention: GQA with RoPE/M-RoPE, sliding windows, qk-norm, KV caches.
+"""Attention: GQA with RoPE/M-RoPE, sliding windows, qk-norm, KV caches;
+latent attention (DeepSeek-V2 MLA) for training.
 
 Two execution paths:
   * dense masked attention for short sequences / decode (1 query token);
@@ -7,7 +8,8 @@ Two execution paths:
     this is what makes 32k-prefill fit in ``memory_analysis`` and it is the
     pure-jnp oracle for the Pallas flash kernel in ``repro.kernels``.
 
-All functions are batch-first: q (B, Sq, H, D), k/v (B, Skv, Kv, D).
+All functions are batch-first: q (B, Sq, H, D), k (B, Skv, Kv, D), v
+(B, Skv, Kv, Dv); Dv == D except under latent attention.
 """
 from __future__ import annotations
 
@@ -17,9 +19,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import (Runtime, apply_rope, rms_norm_headwise)
+from repro.models.layers import (Runtime, apply_norm, apply_rope,
+                                 rms_norm_headwise)
 
 NEG_INF = -1e30
+SCOPE_MLA = "mla"    # latent attention's projections, latent norm and rope
 
 
 def init_attention(cfg, key):
@@ -69,7 +73,7 @@ def _attend_dense(q, k, v, q_pos, k_pos, window, scale):
     logits = jnp.where(mask[None, None, None], logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", w, v)
-    return out.reshape(B, Sq, H, D)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -89,7 +93,7 @@ def _attend_blocked(q, k, v, window, scale, q_chunk, kv_chunk):
     positions (k_pos > every real q_pos) and padded q rows are sliced off.
     """
     B, S, H, D = q.shape
-    Kv = k.shape[2]
+    Kv, Dv = k.shape[2], v.shape[-1]
     G = H // Kv
     q_chunk = min(q_chunk, S)
     kv_chunk = min(kv_chunk, S)
@@ -102,7 +106,7 @@ def _attend_blocked(q, k, v, window, scale, q_chunk, kv_chunk):
 
     qg = q.reshape(B, nq, q_chunk, Kv, G, D).transpose(1, 0, 2, 3, 4, 5)
     kc = k.reshape(B, nk, kv_chunk, Kv, D).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, nk, kv_chunk, Kv, D).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, nk, kv_chunk, Kv, Dv).transpose(1, 0, 2, 3, 4)
 
     @jax.checkpoint
     def q_step(_, qi_qblk):
@@ -126,29 +130,34 @@ def _attend_blocked(q, k, v, window, scale, q_chunk, kv_chunk):
 
         m0 = jnp.full((B, Kv, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Kv, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, Kv, G, q_chunk, D), jnp.float32)
+        a0 = jnp.zeros((B, Kv, G, q_chunk, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0), (jnp.arange(nk), kc, vc))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return None, out.astype(q.dtype)                     # (B,Kv,G,qc,D)
 
     _, outs = jax.lax.scan(q_step, None, (jnp.arange(nq), qg))
-    # outs: (nq, B, Kv, G, qc, D) -> (B, Sp, H, D) -> drop padded rows
-    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sp, H, D)
+    # outs: (nq, B, Kv, G, qc, Dv) -> (B, Sp, H, Dv) -> drop padded rows
+    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sp, H, Dv)
     return out[:, :S]
 
 
-def sdpa_causal(q, k, v, window=0, rt: Optional[Runtime] = None):
-    """Self-attention where q/k/v cover the same positions 0..S-1."""
+def sdpa_causal(q, k, v, window=0, rt: Optional[Runtime] = None,
+                scale: Optional[float] = None):
+    """Self-attention where q/k/v cover the same positions 0..S-1;
+    ``scale`` defaults to 1/sqrt(q/k head width)."""
     rt = rt or Runtime()
     S = q.shape[1]
-    scale = q.shape[-1] ** -0.5
-    if rt.attn_impl == "pallas" and S >= 128 and q.shape[-1] % 64 == 0:
+    if rt.attn_impl == "pallas" and S >= 128 and q.shape[-1] % 64 == 0 \
+            and v.shape[-1] % 64 == 0:
         # TPU hot path: Pallas flash kernel (interpret-mode on CPU)
         from repro.kernels import ops as kernel_ops
         return rt.per_shard(
-            lambda q, k, v: kernel_ops.attention(q, k, v, window=window),
+            lambda q, k, v: kernel_ops.attention(q, k, v, window=window,
+                                                 scale=scale),
             q, k, v, heads=True)
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     if S <= rt.attn_min_chunked_len:
         pos = jnp.arange(S)
         return _attend_dense(q, k, v, pos, pos, window, scale)
@@ -275,6 +284,69 @@ def _project_qkv(cfg, p, x, rt: Runtime):
         q = rms_norm_headwise(p["q_norm"], q, cfg.norm_eps)
         k = rms_norm_headwise(p["k_norm"], k, cfg.norm_eps)
     return q, k, v
+
+
+# ---------------------------------------------------------------------------
+# latent attention (DeepSeek-V2 MLA, training)
+# ---------------------------------------------------------------------------
+
+def init_mla(cfg, key):
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": jax.random.normal(ks[0], (d, h * qk)) * d ** -0.5,
+        "wkv_a": jax.random.normal(ks[1], (d, r + cfg.qk_rope_head_dim))
+        * d ** -0.5,
+        "kv_norm": jnp.ones((r,)),
+        "wkv_b": jax.random.normal(
+            ks[2], (r, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)))
+        * r ** -0.5,
+        "wo": jax.random.normal(ks[3], (h * cfg.v_head_dim, d))
+        * (h * cfg.v_head_dim) ** -0.5,
+    }
+
+
+def _rope_pairs(x, rope_ang):
+    """DeepSeek-V2's rotation of interleaved pairs (x[2i], x[2i+1]): the
+    rotated dims are first laid out as two halves, then rotated as such;
+    they stay in that layout, on q and k alike."""
+    D = x.shape[-1]
+    x = x.reshape(x.shape[:-1] + (D // 2, 2)).swapaxes(-1, -2) \
+        .reshape(x.shape)
+    return apply_rope(x, rope_ang)
+
+
+def mla_block(cfg, p, x, rope_ang, rt: Runtime):
+    """Latent attention sublayer (training and cache-less prefill):
+    q from x directly; keys and values up from a normed latent
+    c = RMSNorm(x Wkv_a[:, :r]); one rotated key part, x Wkv_a[:, r:], is
+    shared by every head.  x (B,S,d) -> (B,S,d)."""
+    B, S, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = x.dtype
+    with jax.named_scope(SCOPE_MLA):
+        q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(dt))
+        q = q.reshape(B, S, h, nope + rope)
+        ckv = jnp.einsum("bsd,de->bse", x, p["wkv_a"].astype(dt))
+        c, k_pe = ckv[..., :r], ckv[..., r:]
+        c = apply_norm({"scale": p["kv_norm"]}, c, cfg.norm_eps, rt)
+        kv = jnp.einsum("bsr,re->bse", c, p["wkv_b"].astype(dt))
+        kv = kv.reshape(B, S, h, nope + dv)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        q_pe = _rope_pairs(q[..., nope:], rope_ang)
+        k_pe = _rope_pairs(k_pe[:, :, None, :], rope_ang)
+        q = jnp.concatenate([q[..., :nope], q_pe], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (B, S, h, rope))], -1)
+    q = rt.c("heads_q", q)
+    k = rt.c("heads_kv", k)
+    v = rt.c("heads_kv", v)
+    out = sdpa_causal(q, k, v, 0, rt, scale=cfg.attn_scale)
+    out = out.reshape(B, S, h * dv)
+    out = jnp.einsum("bse,ed->bsd", out, p["wo"].astype(out.dtype))
+    return rt.c("act_btd", out)
 
 
 def _cp_attend(q, k, v, window, scale, axis):

@@ -30,7 +30,8 @@ class Runtime:
     attn_min_chunked_len: int = 2048    # below this, plain masked attention
     rwkv_chunk: int = 64
     mamba_chunk: int = 256
-    moe_impl: str = "auto"              # 'dense' | 'dropping' | 'ep' | 'auto'
+    moe_impl: str = "auto"              # 'dense' | 'dropping' | 'dropless'
+                                        # | 'ep' | 'auto'
     moe_groups: int = 1                 # data shards = dispatch groups
     moe_stat_axes: tuple = ()           # mesh axes to psum router load stats
                                         # over (set inside shard_map bodies —
@@ -169,9 +170,34 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def rope_angles(positions, head_dim, theta):
+def yarn_freqs(dim: int, theta: float, yarn) -> jnp.ndarray:
+    """YaRN's inverse frequencies (DeepSeek-V2's ``yarn_find_correction_range``
+    and linear ramp): the fastest-turning frequencies keep the plain rope
+    value, the slowest are divided by the factor, a ramp between the
+    correction dims ``low`` and ``high`` blends the two."""
+    extra = rope_freqs(dim, theta)
+    inter = extra / yarn.factor
+
+    def correction_dim(rotations):
+        return (dim * np.log(yarn.original_max_position
+                             / (rotations * 2 * np.pi))) / (2 * np.log(theta))
+
+    low = max(int(np.floor(correction_dim(yarn.beta_fast))), 0)
+    high = min(int(np.ceil(correction_dim(yarn.beta_slow))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return inter * (1.0 - keep) + extra * keep
+
+
+def rope_angles(positions, head_dim, theta, yarn=None):
     """positions: (..., S) int -> angles (..., S, head_dim//2) fp32."""
-    inv = rope_freqs(head_dim, theta)
+    if yarn is not None and yarn.factor > 1:
+        inv = yarn_freqs(head_dim, theta, yarn)
+    else:
+        inv = rope_freqs(head_dim, theta)
     return positions.astype(jnp.float32)[..., None] * inv
 
 

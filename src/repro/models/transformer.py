@@ -76,7 +76,9 @@ def _init_layer(cfg: ModelConfig, i: int, key) -> Dict[str, Any]:
     kind, is_moe = _sig(cfg, i)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {"norm1": init_norm(cfg, k1), "norm2": init_norm(cfg, k2)}
-    if kind == "attn":
+    if kind == "attn" and cfg.mla:
+        p["mixer"] = attn_lib.init_mla(cfg, k3)
+    elif kind == "attn":
         p["mixer"] = attn_lib.init_attention(cfg, k3)
     elif kind == "rwkv6":
         p["mixer"] = rwkv_lib.init_rwkv_time_mix(cfg, k3)
@@ -164,7 +166,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype, rt: Runtime):
 
 def _apply_layer(cfg, sig, lp, h, rope_ang, rt: Runtime, cache=None,
                  paged=None):
-    """-> (h, new_cache, aux_loss).
+    """-> (h, new_cache, aux_loss); ``_apply_layer_load`` adds the expert
+    load."""
+    return _apply_layer_load(cfg, sig, lp, h, rope_ang, rt, cache,
+                             paged)[:3]
+
+
+def _apply_layer_load(cfg, sig, lp, h, rope_ang, rt: Runtime, cache=None,
+                      paged=None):
+    """-> (h, new_cache, aux_loss, expert_load); ``expert_load`` counts
+    the routed items each held expert of an MoE layer computed (None
+    elsewhere, and on the expert-parallel paths).
 
     With ``rt.tp_reduce_axis`` set (Megatron-TP inside a manual pipeline
     stage), the partial mixer/ffn outputs are psummed over the model axis
@@ -175,9 +187,16 @@ def _apply_layer(cfg, sig, lp, h, rope_ang, rt: Runtime, cache=None,
     psum/pmean bookkeeping produce exact gradients.)"""
     kind, is_moe = sig
     aux = jnp.zeros((), jnp.float32)
+    load = None
 
     x = apply_norm(lp["norm1"], h, cfg.norm_eps, rt)
-    if kind == "attn":
+    if kind == "attn" and cfg.mla:
+        if cache is not None or paged is not None:
+            raise NotImplementedError("latent attention has no KV cache "
+                                      "here: training and prefill only")
+        mix = attn_lib.mla_block(cfg, lp["mixer"], x, rope_ang, rt)
+        new_cache = None
+    elif kind == "attn":
         mix, new_mix_cache = attn_lib.attention_block(
             cfg, lp["mixer"], x, rope_ang, rt,
             cache=None if cache is None else cache["kv"], paged=paged)
@@ -202,11 +221,11 @@ def _apply_layer(cfg, sig, lp, h, rope_ang, rt: Runtime, cache=None,
         if new_cache is not None:
             new_cache["ffn"] = new_ffn
     elif is_moe:
-        ffn, aux = moe_lib.apply_moe(cfg, lp["ffn"], x, rt)
+        ffn, aux, load = moe_lib.moe_layer(cfg, lp["ffn"], x, rt)
     else:
         ffn = apply_mlp(cfg, lp["ffn"], x, rt)
     h = h + tp_reduce_out(ffn, rt)
-    return h, new_cache, aux
+    return h, new_cache, aux, load
 
 
 # ---------------------------------------------------------------------------
@@ -240,7 +259,7 @@ def _embed_inputs(cfg, params, batch, rt: Runtime, positions):
 
 
 def _rope_for(cfg, batch, positions):
-    hd = cfg.head_dim_
+    hd = cfg.rope_dim
     if cfg.rope == "none":
         return None
     if cfg.rope == "mrope":
@@ -248,7 +267,7 @@ def _rope_for(cfg, batch, positions):
         if pos_ids is None:                     # text-only fallback: t=h=w
             pos_ids = jnp.broadcast_to(positions[None], (3,) + positions.shape)
         return mrope_angles(pos_ids, hd, cfg.rope_theta, cfg.mrope_sections)
-    return rope_angles(positions, hd, cfg.rope_theta)
+    return rope_angles(positions, hd, cfg.rope_theta, cfg.yarn)
 
 
 def forward(cfg: ModelConfig, params, batch, rt: Runtime,
@@ -258,6 +277,14 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
     batch: tokens (B,S) [or embeds (B,S,d)], optional position_ids (3,B,S),
     optional pos (scalar absolute offset, decode/continuation).
     """
+    logits, new_cache, aux, _ = _forward(cfg, params, batch, rt, cache)
+    return logits, new_cache, aux
+
+
+def _forward(cfg: ModelConfig, params, batch, rt: Runtime, cache=None):
+    """``forward`` -> (logits, new_cache, aux_loss, expert_loads): the
+    routed items each held expert computed, one (n_held,) row per MoE
+    layer that reports them (``_apply_layer_load``), or None."""
     if "embeds" in batch:
         B, S = batch["embeds"].shape[:2]
     else:
@@ -289,25 +316,29 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
                 "(no prefix, period 1); Strategy.to_plan validates this")
         with jax.named_scope(SCOPE_BLOCKS):
             h, aux_total = _pipeline_blocks(cfg, params, h, rope_ang, rt)
-        return _head(cfg, params, h, rt), None, aux_total
+        return _head(cfg, params, h, rt), None, aux_total, None
 
     new_prefix_caches = []
+    loads = []
     for j, i in enumerate(prefix):
         c = None if cache is None else cache["prefix"][j]
         with jax.named_scope(SCOPE_BLOCKS):
-            h, nc, aux = _apply_layer(cfg, _sig(cfg, i), params["prefix"][j],
-                                      h, rope_ang, rt, c, paged)
+            h, nc, aux, load = _apply_layer_load(
+                cfg, _sig(cfg, i), params["prefix"][j], h, rope_ang, rt, c,
+                paged)
         aux_total += aux
         new_prefix_caches.append(nc)
+        if load is not None:
+            loads.append(load[None])
 
     new_block_caches = None
     if n_blocks:
         sigs = [_sig(cfg, start + pos) for pos in range(period)]
 
-        apply = _apply_layer
+        apply = _apply_layer_load
         if rt.remat_inner:
             # cfg, sig and rt are static (hashable frozen dataclasses)
-            apply = jax.checkpoint(_apply_layer, static_argnums=(0, 1, 5))
+            apply = jax.checkpoint(_apply_layer_load, static_argnums=(0, 1, 5))
 
         prefetch = rt.gather_prefetch and rt.gather_params is not None
 
@@ -324,7 +355,7 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
                 h_, aux_ = carry
                 lps = xs[:period]
             caches = xs[period:] if cache is not None else [None] * period
-            new_caches = []
+            new_caches, block_loads = [], []
             for pos in range(period):
                 lp = lps[pos]
                 if not prefetch and rt.gather_params is not None:
@@ -333,11 +364,14 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
                     # variant and stays inside the scan (per-layer FSDP
                     # gather) instead of being hoisted over the whole stack.
                     lp = rt.gather_params(lp)
-                h_, nc, a = apply(cfg, sigs[pos], lp, h_,
-                                  rope_ang, rt, caches[pos], paged)
+                h_, nc, a, load = apply(cfg, sigs[pos], lp, h_,
+                                        rope_ang, rt, caches[pos], paged)
                 aux_ += a
                 new_caches.append(nc)
-            ys = tuple(new_caches) if cache is not None else None
+                if load is not None:
+                    block_loads.append(load)
+            ys = (tuple(new_caches) if cache is not None else None,
+                  jnp.stack(block_loads) if block_loads else None)
             new_carry = (h_, aux_, nxt) if prefetch else (h_, aux_)
             return new_carry, ys
 
@@ -360,10 +394,12 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
                 carry0 = (h, aux_total)
             if cache is not None:
                 xs = xs + tuple(cache["blocks"])
-            out_carry, ys = jax.lax.scan(block_fn, carry0, xs)
+            out_carry, (ys, block_loads) = jax.lax.scan(block_fn, carry0, xs)
         h, aux_total = out_carry[0], out_carry[1]
         if cache is not None:
             new_block_caches = list(ys)
+        if block_loads is not None:      # (n_blocks, per block, n_held)
+            loads.append(block_loads.reshape((-1,) + block_loads.shape[2:]))
 
     logits = _head(cfg, params, h, rt)
 
@@ -372,7 +408,8 @@ def forward(cfg: ModelConfig, params, batch, rt: Runtime,
         new_cache = {"prefix": new_prefix_caches, "blocks": new_block_caches or []}
         if paged is not None:
             new_cache["paged"] = paged
-    return logits, new_cache, aux_total
+    return (logits, new_cache, aux_total,
+            jnp.concatenate(loads) if loads else None)
 
 
 def _head(cfg: ModelConfig, params, h, rt: Runtime):
@@ -488,8 +525,11 @@ def _pipeline_blocks(cfg: ModelConfig, params, h, rope_ang, rt: Runtime):
 # ---------------------------------------------------------------------------
 
 def loss_fn(cfg: ModelConfig, params, batch, rt: Runtime):
-    """Next-token cross entropy; labels < 0 are masked."""
-    logits, _, aux = forward(cfg, params, batch, rt)
+    """Next-token cross entropy; labels < 0 are masked.  Where MoE layers
+    report their expert loads, the metrics add ``expert_items`` (routed
+    items the held experts computed, all layers) and ``expert_load_max``
+    (the most loaded held expert over the mean, averaged over layers)."""
+    logits, _, aux, loads = _forward(cfg, params, batch, rt)
     with jax.named_scope(SCOPE_XENT_LOSS):
         labels = batch["labels"]
         lf = logits.astype(jnp.float32)
@@ -498,7 +538,12 @@ def loss_fn(cfg: ModelConfig, params, batch, rt: Runtime):
             lf, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
         mask = (labels >= 0).astype(jnp.float32)
         nll = ((lse - ll) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-        return nll + aux, {"nll": nll, "aux": aux, "ntok": mask.sum()}
+    metrics = {"nll": nll, "aux": aux, "ntok": mask.sum()}
+    if loads is not None:
+        mean = jnp.maximum(loads.mean(-1), 1e-9)
+        metrics.update(expert_items=loads.sum(),
+                       expert_load_max=jnp.mean(loads.max(-1) / mean))
+    return nll + aux, metrics
 
 
 def prefill(cfg, params, batch, rt: Runtime, max_len: int):

@@ -50,7 +50,8 @@ def _decay_mask(path) -> bool:
     """No weight decay for norms, biases, 1-d params."""
     leaf = getattr(path[-1], "key", getattr(path[-1], "name", str(path[-1])))
     return leaf not in ("scale", "bias", "b_dt", "conv_b", "w0",
-                        "maa_x", "maa_k", "maa_r", "bq", "bk", "bv")
+                        "maa_x", "maa_k", "maa_r", "bq", "bk", "bv",
+                        "kv_norm")
 
 
 def adamw_update(cfg: AdamWConfig, params, grads, state, lr_scale=1.0):

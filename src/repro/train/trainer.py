@@ -93,10 +93,10 @@ def make_train_step(cfg: ModelConfig, rt: Runtime, tc: TrainConfig,
                 1, tc.grad_accum, micro, (g0, l0, m0))
             grads = jax.tree.map(lambda g: g / tc.grad_accum, grads)
             loss_val = lsum / tc.grad_accum
-            # token counts add across microbatches; everything else is a
-            # per-microbatch mean
+            # token and item counts add across microbatches; everything
+            # else is a per-microbatch mean
             metrics: Dict[str, Any] = {
-                k: v if k == "ntok" else v / tc.grad_accum
+                k: v if k in ("ntok", "expert_items") else v / tc.grad_accum
                 for k, v in msum.items()}
         else:
             (loss_val, metrics), grads = jax.value_and_grad(

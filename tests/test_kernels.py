@@ -89,6 +89,37 @@ def test_flash_attention_grads(B, S, H, Kv, D, window):
         assert err < 1e-3, (name, err)
 
 
+@pytest.mark.parametrize("H,Kv,Dqk,Dv,scale", [
+    (4, 4, 192, 128, 0.1147),   # latent attention: q/k 128 + 64, v 128, YaRN
+    (4, 2, 128, 128, None),     # one width, default scale: the qwen3 path
+])
+def test_flash_attention_value_head_and_scale(H, Kv, Dqk, Dv, scale):
+    """A value head narrower than q/k's and an explicit softmax scale:
+    output and the three gradients agree with dense f32 attention."""
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (1, 256, H, Dqk)) * 0.5
+    k = jax.random.normal(ks[1], (1, 256, Kv, Dqk)) * 0.5
+    v = jax.random.normal(ks[2], (1, 256, Kv, Dv)) * 0.5
+    cot = jax.random.normal(ks[3], (1, 256, H, Dv))
+
+    def pallas(q, k, v):
+        return flash_attention(q, k, v, block_q=64, block_kv=128,
+                               interpret=True, scale=scale)
+
+    def dense(q, k, v):
+        return ref.attention_ref(q, k, v, scale=scale)
+
+    out = pallas(q, k, v)
+    assert out.shape == (1, 256, H, Dv)
+    assert float(jnp.max(jnp.abs(out - dense(q, k, v)))) < 2e-5
+    g_pl = jax.grad(lambda *a: jnp.sum(pallas(*a) * cot), (0, 1, 2))(q, k, v)
+    g_rf = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g_pl, g_rf):
+        assert a.shape == b.shape
+        err = float(jnp.max(jnp.abs(a - b)))
+        assert err < 1e-3, (name, err)
+
+
 @pytest.mark.parametrize("S", [160, 200, 300])
 def test_flash_attention_default_blocks_ragged_s(S):
     """Default 128/256 blocks with 128 < S < 2*block_q: the padded length

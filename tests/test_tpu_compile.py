@@ -78,6 +78,13 @@ def _flash_fwd_bwd(q, k, v, cot):
     return out, pull(cot)
 
 
+def _flash_mla_fwd_bwd(q, k, v, cot):
+    """Latent attention's heads: q/k 192 wide, v 128, YaRN's scale."""
+    out, pull = jax.vjp(lambda q, k, v: ops.attention(
+        q, k, v, interpret=False, scale=0.114722), q, k, v)
+    return out, pull(cot)
+
+
 def _rmsnorm_fwd(x, s):
     return ops.rmsnorm(x, s, interpret=False)
 
@@ -113,6 +120,11 @@ CASES = {
     "flash_fwd_bwd": (_flash_fwd_bwd,
                       lambda dt: [(Q, dt), (K, dt), (K, dt), (Q, dt)],
                       {FWD, DQ, DKV}),
+    # deepseek-v2-lite: 16 heads, q/k 128 + 64 rotated, v 128, at 8k
+    "flash_fwd_bwd_mla": (_flash_mla_fwd_bwd, lambda dt: [
+        ((1, 8192, 16, 192), dt), ((1, 8192, 16, 192), dt),
+        ((1, 8192, 16, 128), dt), ((1, 8192, 16, 128), dt)],
+        {FWD, DQ, DKV}),
     "rmsnorm_fwd": (_rmsnorm_fwd, lambda dt: [(X, dt), ((DM,), F32)],
                     {"rmsnorm_fwd"}),
     "rmsnorm_fwd_bwd": (_rmsnorm_fwd_bwd,

@@ -29,6 +29,12 @@ TPU-native design (not a CUDA port, see DESIGN.md §2):
     at its own width, so no head is padded.
   * causal + sliding-window masks built from absolute block offsets with
     broadcasted iota (2D, as the TPU requires).
+  * block pairs with no visible entry (above the causal diagonal, below a
+    sliding window, or keys wholly in padding) are skipped: each kernel
+    guards its block compute with ``pl.when`` and clamps the index maps of
+    the operands that walk the skipped axis to the nearest visible block,
+    so a skipped step starts no DMA.  A skipped pair would add exactly
+    zero, so outputs and gradients are those of running every pair.
 
 ``flash_attention`` carries a ``jax.custom_vjp``, so ``jax.grad`` through it
 runs the Pallas backward kernels: the training hot path (fwd + bwd) executes
@@ -61,6 +67,67 @@ def _block_mask(qi, kj, block_q, block_kv, causal, window, seq_len):
     return mask
 
 
+def visible_blocks(i, *, q_major, block_q, block_kv, causal, window,
+                   seq_len):
+    """Inclusive range (lo, hi) of the blocks on the other axis that hold
+    an entry ``_block_mask`` leaves visible: the kv blocks seen by q block
+    ``i`` when ``q_major``, else the q blocks that see kv block ``i``.
+
+    lo always names a block of the grid, so ``max(min(x, hi), lo)`` clamps
+    an index into the range; hi < lo only for a kv block wholly in padding,
+    which no q block sees.  ``i`` may be a Python int or a traced grid
+    index; every operand of a division is non-negative.
+    """
+    bq, bk = block_q, block_kv
+    if q_major:
+        lo, hi = 0, (seq_len - 1) // bk
+        if causal:          # kv block j is seen if j*bk <= i*bq + bq - 1
+            hi = jnp.minimum(hi, (i * bq + bq - 1) // bk)
+        if window:          # ... and if (j+1)*bk - 1 > i*bq - window
+            lo = jnp.maximum(i * bq - window + 1, 0) // bk
+        return lo, hi
+    lo, hi = 0, (seq_len - 1) // bq
+    if causal:
+        lo = i * bk // bq
+    if window:
+        hi = jnp.minimum(hi, ((i + 1) * bk + window - 2) // bq)
+    return lo, jnp.where(i * bk < seq_len, hi, -1)
+
+
+def _clamped_maps(bq, bk, causal, window, seq_len):
+    """-> (kv_block(i, j), q_block(j, i)): the block an operand on the
+    skipped axis loads at step (q block i, kv block j), moved into the
+    visible range, so a skipped step names the block already in VMEM and
+    starts no DMA (as the ``below_or_on_diag`` maps of JAX's Pallas TPU
+    flash attention do)."""
+    vis = functools.partial(visible_blocks, block_q=bq, block_kv=bk,
+                            causal=causal, window=window, seq_len=seq_len)
+
+    def clamp(x, lo, hi):                   # lo when the range is empty
+        return jnp.maximum(jnp.minimum(x, hi), lo)
+
+    def kv_block(i, j):
+        return clamp(j, *vis(i, q_major=True))
+
+    def q_block(j, i):
+        return clamp(i, *vis(j, q_major=False))
+
+    return kv_block, q_block
+
+
+def block_pairs(seq_len, *, block_q, block_kv, causal=True, window=0):
+    """-> (block pairs the kernels run, block pairs in the grid) for one
+    (batch row, head) at the blocks ``flash_attention`` would use."""
+    bq, bk, Sp = _blocks(seq_len, block_q, block_kv)
+    run = 0
+    for i in range(Sp // bq):
+        lo, hi = visible_blocks(i, q_major=True, block_q=bq, block_kv=bk,
+                                causal=causal, window=window,
+                                seq_len=seq_len)
+        run += max(int(hi) - int(lo) + 1, 0)
+    return run, (Sp // bq) * (Sp // bk)
+
+
 # ---------------------------------------------------------------------------
 # forward kernel (emits o and the logsumexp residual)
 # ---------------------------------------------------------------------------
@@ -76,25 +143,33 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                    # (bq, D)
-    k = k_ref[0].astype(jnp.float32)                    # (bk, D)
-    v = v_ref[0].astype(jnp.float32)
+    lo, hi = visible_blocks(qi, q_major=True, block_q=block_q,
+                            block_kv=block_kv, causal=causal, window=window,
+                            seq_len=seq_len)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (bq,bk)
+    @pl.when((kj >= lo) & (kj <= hi))
+    def _compute():
+        q = q_ref[0].astype(jnp.float32)                # (bq, D)
+        k = k_ref[0].astype(jnp.float32)                # (bk, D)
+        v = v_ref[0].astype(jnp.float32)
 
-    mask = _block_mask(qi, kj, block_q, block_kv, causal, window, seq_len)
-    s = jnp.where(mask, s, NEG_INF)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
 
-    m_prev = m_scr[...]                                 # (bq, 1)
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    # fully-masked rows: make exp(NEG_INF - NEG_INF)=1 contributions vanish
-    p = jnp.where(mask, p, 0.0)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_scr[...] = m_new
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(p, v)
+        mask = _block_mask(qi, kj, block_q, block_kv, causal, window,
+                           seq_len)
+        s = jnp.where(mask, s, NEG_INF)                 # (bq, bk)
+
+        m_prev = m_scr[...]                             # (bq, 1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        # fully-masked rows: make exp(NEG_INF - NEG_INF)=1 contributions
+        # vanish
+        p = jnp.where(mask, p, 0.0)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(p, v)
 
     @pl.when(kj == n_kv - 1)
     def _finalize():
@@ -118,21 +193,29 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[0].astype(jnp.float32)                    # (bq, D)
-    k = k_ref[0].astype(jnp.float32)                    # (bk, D)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)                  # (bq, Dv)
-    lse = lse_ref[0].T                                  # (bq, 1)
-    delta = delta_ref[0].T                              # (bq, 1)
+    lo, hi = visible_blocks(qi, q_major=True, block_q=block_q,
+                            block_kv=block_kv, causal=causal, window=window,
+                            seq_len=seq_len)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    mask = _block_mask(qi, kj, block_q, block_kv, causal, window, seq_len)
-    # recompute probabilities from the saved logsumexp; masked entries are
-    # zeroed explicitly so padded/fully-masked rows (lse == NEG_INF) vanish
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)        # (bq, bk)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))      # (bq, bk)
-    ds = p * (dp - delta)
-    dq_scr[...] += jax.lax.dot(ds, k) * scale
+    @pl.when((kj >= lo) & (kj <= hi))
+    def _compute():
+        q = q_ref[0].astype(jnp.float32)                # (bq, D)
+        k = k_ref[0].astype(jnp.float32)                # (bk, D)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)              # (bq, Dv)
+        lse = lse_ref[0].T                              # (bq, 1)
+        delta = delta_ref[0].T                          # (bq, 1)
+
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        mask = _block_mask(qi, kj, block_q, block_kv, causal, window,
+                           seq_len)
+        # recompute probabilities from the saved logsumexp; masked entries
+        # are zeroed explicitly so padded/fully-masked rows (lse ==
+        # NEG_INF) vanish
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)    # (bq, bk)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))  # (bq,bk)
+        ds = p * (dp - delta)
+        dq_scr[...] += jax.lax.dot(ds, k) * scale
 
     @pl.when(kj == n_kv - 1)
     def _finalize():
@@ -154,20 +237,28 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[0].astype(jnp.float32)                    # (bq, D)
-    k = k_ref[0].astype(jnp.float32)                    # (bk, D)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)                  # (bq, Dv)
-    lse = lse_ref[0].T                                  # (bq, 1)
-    delta = delta_ref[0].T                              # (bq, 1)
+    lo, hi = visible_blocks(kj, q_major=False, block_q=block_q,
+                            block_kv=block_kv, causal=causal, window=window,
+                            seq_len=seq_len)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    mask = _block_mask(qi, kj, block_q, block_kv, causal, window, seq_len)
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)        # (bq, bk)
-    dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta)
-    dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ()))) * scale
+    @pl.when((qi >= lo) & (qi <= hi))
+    def _compute():
+        q = q_ref[0].astype(jnp.float32)                # (bq, D)
+        k = k_ref[0].astype(jnp.float32)                # (bk, D)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)              # (bq, Dv)
+        lse = lse_ref[0].T                              # (bq, 1)
+        delta = delta_ref[0].T                          # (bq, 1)
+
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        mask = _block_mask(qi, kj, block_q, block_kv, causal, window,
+                           seq_len)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)    # (bq, bk)
+        dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
+        ds = p * (dp - delta)
+        dk_scr[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ()))) * scale
 
     @pl.when(t == n_t - 1)
     def _finalize():
@@ -185,6 +276,12 @@ def _dims(q_shape, k_shape, block_q, block_kv):
     Kv = k_shape[2]
     assert H % Kv == 0, (H, Kv)
     G = H // Kv
+    bq, bk, Sp = _blocks(S, block_q, block_kv)
+    return B, S, H, D, Kv, G, bq, bk, Sp
+
+
+def _blocks(S, block_q, block_kv):
+    """-> (bq, bk, Sp): the blocks a length-S row runs at, padded length."""
     bq = min(block_q, S)
     Sp = -(-S // bq) * bq
     # bk must divide the padded length exactly or tail blocks are dropped
@@ -192,7 +289,7 @@ def _dims(q_shape, k_shape, block_q, block_kv):
     bk = min(block_kv, Sp)
     if Sp % bk:
         bk = bq
-    return B, S, H, D, Kv, G, bq, bk, Sp
+    return bq, bk, Sp
 
 
 def _group_q(x, Kv, G, Sp):
@@ -230,6 +327,7 @@ def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret,
     Dv = v.shape[-1]
     nq, nk = Sp // bq, Sp // bk
     qg, kg, vg = _group(q, k, v, B, Sp, H, Kv, G, D, S)
+    kv_block, _ = _clamped_maps(bq, bk, causal, window, S)
 
     kernel = functools.partial(
         _flash_kernel, scale=_scale(scale, D), block_q=bq, block_kv=bk,
@@ -240,8 +338,10 @@ def _flash_forward(q, k, v, causal, window, block_q, block_kv, interpret,
         grid=(B * Kv * G, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j, G=G: (b // G, j, 0)),
+            pl.BlockSpec((1, bk, D),
+                         lambda b, i, j: (b // G, kv_block(i, j), 0)),
+            pl.BlockSpec((1, bk, Dv),
+                         lambda b, i, j: (b // G, kv_block(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
@@ -281,6 +381,7 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, scale,
                     axis=-1)[:, None, :]                # (N, 1, Sp) like lse
 
     scale = _scale(scale, D)
+    kv_block, q_block = _clamped_maps(bq, bk, causal, window, S)
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, block_q=bq, block_kv=bk,
         n_kv=nk, causal=causal, window=window, seq_len=S)
@@ -289,8 +390,10 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, scale,
         grid=(B * Kv * G, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j, G=G: (b // G, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, i, j, G=G: (b // G, j, 0)),
+            pl.BlockSpec((1, bk, D),
+                         lambda b, i, j: (b // G, kv_block(i, j), 0)),
+            pl.BlockSpec((1, bk, Dv),
+                         lambda b, i, j: (b // G, kv_block(i, j), 0)),
             pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
@@ -307,20 +410,23 @@ def _flash_backward(causal, window, block_q, block_kv, interpret, scale,
         _flash_bwd_dkv_kernel, scale=scale, block_q=bq, block_kv=bk,
         n_q=nq, n_t=n_t, causal=causal, window=window, seq_len=S)
     # q-side blocks walk (group g, q block i) = (t // nq, t % nq)
+    def q_idx(b, j, t):
+        return b * G + t // nq, q_block(j, t % nq), 0
+
+    def row_idx(b, j, t):
+        n, i, _ = q_idx(b, j, t)
+        return n, 0, i
+
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(B * Kv, nk, n_t),
         in_specs=[
-            pl.BlockSpec((1, bq, D),
-                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, t % nq, 0)),
+            pl.BlockSpec((1, bq, D), q_idx),
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
             pl.BlockSpec((1, bk, Dv), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bq, Dv),
-                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, t % nq, 0)),
-            pl.BlockSpec((1, 1, bq),
-                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, 0, t % nq)),
-            pl.BlockSpec((1, 1, bq),
-                         lambda b, j, t, G=G, nq=nq: (b * G + t // nq, 0, t % nq)),
+            pl.BlockSpec((1, bq, Dv), q_idx),
+            pl.BlockSpec((1, 1, bq), row_idx),
+            pl.BlockSpec((1, 1, bq), row_idx),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ref
+from repro.kernels import flash_attention as fa
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.rwkv6 import wkv6
@@ -155,6 +156,120 @@ def test_flash_attention_grads_mixed_blocks():
         ref.attention_ref(q, k, v) * cot), (0, 1, 2))(q, k, v)
     for a, b in zip(g_pl, g_rf):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-3
+
+
+# ---------------------------------------------------------------------------
+# skipped block pairs: the visible range against the mask, the share of
+# pairs run, and outputs/gradients where most pairs are skipped
+# ---------------------------------------------------------------------------
+
+VISIBILITY_CASES = [   # (S, block_q, block_kv, causal, window)
+    (512, 64, 64, True, 0),
+    (512, 64, 128, True, 0),        # bq != bk
+    (512, 128, 64, True, 0),
+    (300, 128, 256, True, 0),       # ragged S: bk falls back to bq
+    (160, 128, 64, True, 0),        # a kv block wholly in padding
+    (256, 64, 64, True, 32),        # sliding windows
+    (256, 64, 64, True, 64),
+    (256, 64, 64, True, 100),
+    (512, 64, 128, True, 77),
+    (512, 128, 64, True, 300),
+    (512, 64, 64, False, 0),        # no mask: the whole grid
+    (160, 128, 64, False, 0),
+    (512, 64, 64, False, 100),
+]
+
+
+def _visible_pairs(S, block_q, block_kv, causal, window):
+    """Brute force: (i, j) -> does _block_mask leave an entry visible."""
+    bq, bk, Sp = fa._blocks(S, block_q, block_kv)
+    return bq, bk, {(i, j): bool(fa._block_mask(i, j, bq, bk, causal, window,
+                                                S).any())
+                    for i in range(Sp // bq) for j in range(Sp // bk)}
+
+
+@pytest.mark.parametrize("S,block_q,block_kv,causal,window",
+                         VISIBILITY_CASES)
+def test_visible_blocks_match_block_mask(S, block_q, block_kv, causal,
+                                         window):
+    bq, bk, seen = _visible_pairs(S, block_q, block_kv, causal, window)
+    kw = dict(block_q=bq, block_kv=bk, causal=causal, window=window,
+              seq_len=S)
+    for (i, j), visible in seen.items():
+        lo, hi = fa.visible_blocks(i, q_major=True, **kw)
+        assert (lo <= j <= hi) == visible, ("kv of q block", i, j)
+        lo, hi = fa.visible_blocks(j, q_major=False, **kw)
+        assert (lo <= i <= hi) == visible, ("q of kv block", j, i)
+    assert fa.block_pairs(S, block_q=block_q, block_kv=block_kv,
+                          causal=causal, window=window) \
+        == (sum(seen.values()), len(seen))
+
+
+@pytest.mark.parametrize("S,block_q,block_kv,pairs", [
+    (8192, 256, 512, (272, 512)),   # deepseek-v2-lite at 8k, bf16 blocks
+    (1024, 256, 512, (6, 8)),       # qwen s1024
+    (256, 256, 256, (1, 1)),        # qwen s256x4: nothing to skip
+])
+def test_block_pairs_counts(S, block_q, block_kv, pairs):
+    assert fa.block_pairs(S, block_q=block_q, block_kv=block_kv) == pairs
+
+
+@pytest.mark.parametrize("S,block_q,block_kv,causal,window",
+                         VISIBILITY_CASES)
+def test_skipped_steps_start_no_dma(S, block_q, block_kv, causal, window):
+    """Along each kernel's grid order the clamped operand blocks change no
+    more often than a pair runs: a skipped step names a block already
+    fetched (or the next one to run), so it starts no copy of its own.  A
+    kv block wholly in padding runs nothing and still walks the groups."""
+    bq, bk, seen = _visible_pairs(S, block_q, block_kv, causal, window)
+    Sp = fa._blocks(S, block_q, block_kv)[2]
+    nq, nk = Sp // bq, Sp // bk
+    kv_block, q_block = fa._clamped_maps(bq, bk, causal, window, S)
+    G = 2                               # the dk/dv kernel walks (g, i)
+    fwd = [int(kv_block(i, j)) for i in range(nq) for j in range(nk)]
+    dkv = [(g, int(q_block(j, i))) for j in range(nk) for g in range(G)
+           for i in range(nq)]
+    runs = sum(seen.values())
+    unseen = sum(not any(seen[i, j] for i in range(nq)) for j in range(nk))
+    assert all(0 <= x < nk for x in fwd) and all(0 <= x < nq for _, x in dkv)
+    assert sum(a != b for a, b in zip(fwd, fwd[1:])) + 1 <= runs
+    assert sum(a != b for a, b in zip(dkv, dkv[1:])) + 1 \
+        <= G * (runs + unseen)
+
+
+@pytest.mark.parametrize("H,Kv,Dqk,Dv,window,scale,blocks", [
+    (4, 2, 64, 64, 0, None, (64, 64)),       # 36 of 64 pairs, G = 2
+    (8, 2, 64, 64, 0, None, (64, 128)),      # G = 4, bq != bk
+    (4, 4, 192, 128, 0, 0.1147, (64, 64)),   # latent attention's widths
+    (4, 2, 64, 64, 100, None, (64, 64)),     # window: 21 of 64 pairs
+])
+def test_flash_attention_skipped_pairs(H, Kv, Dqk, Dv, window, scale,
+                                       blocks):
+    """S 512 in small blocks, so most block pairs are skipped: output and
+    the three gradients agree with dense f32 attention."""
+    bq, bk = blocks
+    S = 512
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (1, S, H, Dqk)) * 0.5
+    k = jax.random.normal(ks[1], (1, S, Kv, Dqk)) * 0.5
+    v = jax.random.normal(ks[2], (1, S, Kv, Dv)) * 0.5
+    cot = jax.random.normal(ks[3], (1, S, H, Dv))
+    run, total = fa.block_pairs(S, block_q=bq, block_kv=bk, window=window)
+    assert run <= total * 5 // 8
+
+    def pallas(q, k, v):
+        return flash_attention(q, k, v, window=window, block_q=bq,
+                               block_kv=bk, interpret=True, scale=scale)
+
+    def dense(q, k, v):
+        return ref.attention_ref(q, k, v, window=window, scale=scale)
+
+    assert float(jnp.max(jnp.abs(pallas(q, k, v) - dense(q, k, v)))) < 2e-5
+    g_pl = jax.grad(lambda *a: jnp.sum(pallas(*a) * cot), (0, 1, 2))(q, k, v)
+    g_rf = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g_pl, g_rf):
+        err = float(jnp.max(jnp.abs(a - b)))
+        assert err < 1e-3, (name, err)
 
 
 @pytest.mark.parametrize("shape", [(4, 7, 256), (2, 128, 512), (3, 384)])

@@ -260,40 +260,31 @@ def _write_bench(out_path: str, payload: dict, n_rows: int):
 def _pp_sweep(out_path: str = "results/benchmarks/BENCH_pipeline.json",
               pps=(1, 2, 4), scheds=("gpipe", "1f1b", "1f1b_i2", "zb"),
               ovls=(False, True), n_iter: int = 3):
-    """Predicted vs measured step time for pp in {1,2,4} x schedule in
-    {gpipe, 1f1b, 1f1b_i2, zb} x ZeRO gather overlap {off, on} on 8
-    virtual CPU devices -> BENCH_pipeline.json (CI artifact).
+    """Pipeline sweep over pp in {1,2,4} x schedule in {gpipe, 1f1b,
+    1f1b_i2, zb} x ZeRO gather overlap {off, on} on 8 virtual CPU devices
+    -> BENCH_pipeline.json (CI artifact).
 
-    Measured wall time is a CPU regression signal; the *comparable*
-    quantities across the predicted/measured columns are the per-schedule
-    pipeline bubble fraction (schedule-determined and hardware-free —
-    (P-1)/(M+P-1) for gpipe/1f1b, (P-1)/(vM+P-1) interleaved,
-    2(P-1)/(3M+2P-2) zero-bubble) and the per-schedule peak memory,
-    recorded both predicted (cost model) and measured (compiled-executable
-    memory analysis, where the backend reports one).  The `_ovl` variants
-    flip the double-buffered ZeRO gather prefetch; the bubble probe runs
-    once per schedule (the bubble does not depend on the overlap token).
+    The schedule-comparable columns are analytic: the per-schedule
+    pipeline bubble fraction ((P-1)/(M+P-1) for gpipe/1f1b, (P-1)/(vM+P-1)
+    interleaved, 2(P-1)/(3M+2P-2) zero-bubble), the in-flight activation
+    count, the table's sub-tick census, and the cost model's peak memory,
+    next to the compiled executable's temp bytes where the backend
+    reports them.  The CPU step time is a regression signal only.  The
+    `_ovl` variants flip the double-buffered ZeRO gather prefetch.
     """
     from repro.launch.devices import force_host_device_count
     force_host_device_count(8)
     import jax
-    from repro import strategy as strategy_lib
     from repro.configs import ShapeConfig, get_config, reduced
-    from repro.core.pipeline import (inflight_microbatches, op_tick_counts,
-                                     virtual_stages)
-    from repro.perf.pipeline_probe import measure_bubble
 
     cfg = reduced(get_config("qwen3-0.6b"), n_layers=8)
-    topo = strategy_lib.host_topology()
     shape = ShapeConfig("pp-sweep", 128, 16, "train")
     rows, summary = [], []
     for pp in pps:
         for sched in (scheds if pp > 1 else ("gpipe",)):
             for ovl in ovls:
-                rows.append(_pp_sweep_point(
-                    cfg, topo, shape, pp, sched, ovl, n_iter, summary,
-                    inflight_microbatches, op_tick_counts, virtual_stages,
-                    measure_bubble))
+                rows.append(_pp_sweep_point(cfg, shape, pp, sched, ovl,
+                                            n_iter, summary))
     _write_bench(out_path, {
         "backend": jax.default_backend(), "n_iter": n_iter,
         "arch": cfg.name, "shape": {"seq_len": shape.seq_len,
@@ -302,10 +293,10 @@ def _pp_sweep(out_path: str = "results/benchmarks/BENCH_pipeline.json",
     return summary
 
 
-def _pp_sweep_point(cfg, topo, shape, pp, sched, ovl, n_iter, summary,
-                    inflight_microbatches, op_tick_counts, virtual_stages,
-                    measure_bubble):
+def _pp_sweep_point(cfg, shape, pp, sched, ovl, n_iter, summary):
     """One (pp, sched, ovl) row of the pipeline sweep."""
+    from repro.core.pipeline import (bubble_fraction, inflight_microbatches,
+                                     op_tick_counts, virtual_stages)
     if pp == 1:
         spec = "fsdp" + ("_ovl" if ovl else "")
     else:
@@ -318,39 +309,16 @@ def _pp_sweep_point(cfg, topo, shape, pp, sched, ovl, n_iter, summary,
     row.update(pp=pp, microbatches=strat.microbatches, sched=sched,
                overlap=ovl, virtual_stages=virtual_stages(sched),
                predicted_wps=report.wps,
-               predicted_peak_memory_bytes=report.memory_per_device)
+               predicted_peak_memory_bytes=report.memory_per_device,
+               bubble_predicted=bubble_fraction(pp, strat.microbatches,
+                                                sched))
     if pp > 1:
         row["inflight_microbatches"] = inflight_microbatches(
             pp, strat.microbatches, sched)
         row["op_tick_counts"] = op_tick_counts(
             sched, pp, strat.microbatches)
-        if not ovl:
-            row.update(measure_bubble(cfg, strat, topo, n_iter=n_iter))
-            if row.get("fit_unreliable"):
-                # the two-point fit came out non-increasing — a failed
-                # measurement: no rel_err is recorded (a clamped 0.0
-                # would fabricate a 100% miss), only the flag
-                row["bubble_rel_err"] = None
-                print(f"[bench] warn: {spec} bubble fit unreliable "
-                      "(t(2M) <= t(M); noisy host) — row flagged")
-                rel = 0.0
-            else:
-                rel = abs(row["bubble_measured"]
-                          - row["bubble_predicted"]) \
-                    / row["bubble_predicted"]
-                row["bubble_rel_err"] = round(rel, 3)
-            if not row.get("fit_unreliable") and rel > 0.2:
-                # two-point wall-clock fits are noisy on oversubscribed
-                # CPU hosts; flag it so the artifact is self-describing
-                # (the tier-1 slow test enforces the 20% bound with
-                # retries; this sweep only records the trajectory)
-                print(f"[bench] warn: {spec} measured bubble "
-                      f"{row['bubble_measured']:.3f} is {rel:.0%} off "
-                      f"the predicted {row['bubble_predicted']:.3f} "
-                      "(noisy host?)")
     summary.append((f"pp_sweep_{spec}", t_best * 1e6,
-                    f"bubble{row.get('bubble_measured', 0.0):.3f}"
-                    f"_pred{row.get('bubble_predicted', 0.0):.3f}"
+                    f"pred{row['bubble_predicted']:.3f}"
                     f"_mem{row['predicted_peak_memory_bytes']/2**20:.0f}MiB"))
     return row
 
@@ -901,11 +869,12 @@ def main() -> None:
     ap.add_argument("--kernel_json",
                     default="results/benchmarks/BENCH_kernels.json")
     ap.add_argument("--pp-sweep", dest="pp_sweep", action="store_true",
-                    help="only run the pipeline-parallel sweep (predicted "
-                         "vs measured step time + per-schedule bubble and "
-                         "predicted+measured peak memory for pp in {1,2,4} "
-                         "x {gpipe,1f1b,1f1b_i2,zb} x overlap {off,on} on "
-                         "8 virtual devices) and write BENCH_pipeline.json")
+                    help="only run the pipeline-parallel sweep "
+                         "(analytic bubble, in-flight count and sub-tick "
+                         "census, predicted and compiled peak memory for "
+                         "pp in {1,2,4} x {gpipe,1f1b,1f1b_i2,zb} x "
+                         "overlap {off,on} on 8 virtual devices) and "
+                         "write BENCH_pipeline.json")
     ap.add_argument("--pipeline_json",
                     default="results/benchmarks/BENCH_pipeline.json")
     ap.add_argument("--ep-sweep", dest="ep_sweep", action="store_true",

@@ -44,18 +44,18 @@ psums and the MoE all-to-all run inside the stage — see
 GSPMD all-gathers FSDP-sharded params at entry.
 
 Differentiable: the GPipe path trains through shard_map + ppermute's
-transpose rules; the 1F1B path is a ``jax.custom_vjp`` whose backward runs
-the combined recompute-forward/backward 1F1B tick loop (the primal stores
-only the schedule inputs, so per-stage activation residency really is
-bounded by the warmup depth).
+transpose rules; every other schedule runs through one table-driven
+executor, a ``jax.custom_vjp`` whose backward runs the combined
+recompute-forward/backward tick loop of the schedule's table (the primal
+stores only the schedule inputs, so per-stage activation residency really
+is bounded by the warmup depth).
 """
 from __future__ import annotations
 
 import dataclasses
 import logging
 import re
-import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -212,8 +212,7 @@ def _entry(axes: Tuple[str, ...]):
 
 
 # ---------------------------------------------------------------------------
-# the shared forward tick loop (used by GPipe's differentiable path and as
-# the 1F1B primal)
+# GPipe's forward tick loop (differentiated by autodiff)
 # ---------------------------------------------------------------------------
 
 def _make_fwd_body(stage_fn: Callable, axis: str, n_stages: int):
@@ -398,217 +397,17 @@ class GPipeSchedule(PipelineSchedule):
         return fn(stage_params, x, extras)
 
 
-class OneFOneBSchedule(PipelineSchedule):
-    """1F1B (PipeDream-flush): stage s runs P - s warmup forwards, then
-    alternates one-forward-one-backward, then drains.  Per-stage in-flight
-    activations <= P instead of M.
-
-    Executable via ``jax.custom_vjp``: the primal runs the plain forward
-    tick loop storing only the schedule *inputs*; the backward replays
-    microbatch forwards just-in-time through the pipe (standard remat,
-    like the GPipe path under ``Runtime.remat``) interleaved with the
-    per-microbatch backwards in 1F1B order, holding at most min(M, P)
-    stage-input activations in a ring buffer.
-
-    Tick alignment: stage s forwards microbatch j at tick ``s + j`` during
-    warmup (j < P - s) and ``2j + s`` in steady state; it backwards j at
-    ``2j + 2P - 1 - s`` — so every consumed value was produced by the
-    neighbor exactly one tick earlier, except across the warmup/steady
-    boundary, where receivers *latch* the incoming value until their
-    scheduled tick (neighbors forward idle-tick payloads are ignored).
-    """
-
-    name = "1f1b"
-
-    # -- tick arithmetic (shared by the table and the traced loop) --------
-    @staticmethod
-    def _fwd_tick(P_, M, s, j):
-        return s + j if j < P_ - s else 2 * j + s
-
-    @staticmethod
-    def _bwd_tick(P_, M, s, j):
-        return 2 * j + 2 * P_ - 1 - s
-
-    def tick_table(self, n_stages, n_microbatches):
-        P_, M = n_stages, n_microbatches
-        if M < P_:
-            raise ValueError(f"1f1b needs microbatches >= stages "
-                             f"(got M={M} < P={P_})")
-        total = 2 * (M + P_ - 1)
-        table = [[("idle", -1)] * P_ for _ in range(total)]
-        for s in range(P_):
-            for j in range(M):
-                table[self._fwd_tick(P_, M, s, j)][s] = ("F", j)
-                table[self._bwd_tick(P_, M, s, j)][s] = ("B", j)
-        return table
-
-    def apply(self, stage_fn, stage_params, x, mesh, axis, extras,
-              batch_axes=(), param_specs=None, seq_axis="", tp_axis=""):
-        n_stages = mesh.shape[axis]
-        M = x.shape[0]
-        if M < n_stages:
-            raise ValueError(f"1f1b needs microbatches >= stages "
-                             f"(got M={M} < P={n_stages})")
-        W = min(M, n_stages)            # activation ring depth
-        specs = _resolve_specs(stage_params, x, mesh, axis, extras,
-                               batch_axes, param_specs, seq_axis)
-        fwd_sm = _shard_map(
-            _make_fwd_body(stage_fn, axis, n_stages), mesh,
-            in_specs=(specs.pspec, specs.x_spec, specs.espec),
-            out_specs=(specs.x_spec, P()))
-
-        tok_axes = _token_axes(specs)
-        # Megatron-TP cotangent convention inside the manual loop: the
-        # stage body contains raw psums, so a replicated value's physical
-        # cotangents must SUM across model ranks to the logical one (the
-        # "split" convention — see layers.tp_reduce_out).  Injected
-        # cotangents (dy, d_aux) are therefore divided by tp, and the
-        # final reductions psum back over the model axis.
-        tp_div = mesh.shape[tp_axis] if tp_axis else 1
-        grad_axes = tok_axes + (
-            (tp_axis,) if tp_axis and tp_axis not in tok_axes else ())
-        # per-leaf gradient reduction: sum over the axes this leaf is
-        # replicated across but whose contributions are distinct (token
-        # shards; split model-cotangents under TP).  A leaf already
-        # sharded over 'expert'/'model' owns its slice's cotangent.
-        p_reduce = jax.tree.map(
-            lambda sp: tuple(a for a in grad_axes
-                             if a not in _spec_axes(sp)),
-            specs.pspec, is_leaf=lambda s: isinstance(s, P))
-        # extras feed every stage and every token/head shard
-        e_reduce = (axis,) + grad_axes
-
-        def bwd_body(params_local, xs, extras_local, dy, d_aux):
-            stage = jax.lax.axis_index(axis)
-            Mi = xs.shape[0]
-            mb_shape = xs.shape[1:]
-            total = 2 * (Mi + n_stages - 1)
-            fwd_perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
-            bwd_perm = [(i, (i - 1) % n_stages) for i in range(n_stages)]
-            zeros_mb = jnp.zeros(mb_shape, xs.dtype)
-
-            def is_f_at(s, t):
-                warm_s = n_stages - s
-                jw = t - s
-                is_warm = (jw >= 0) & (jw < warm_s)
-                js = jw // 2
-                steady = (jw >= 0) & (jw % 2 == 0) & (js >= warm_s) & (js < Mi)
-                return is_warm | steady, jnp.clip(
-                    jnp.where(is_warm, jw, js), 0, Mi - 1)
-
-            def is_b_at(s, t):
-                tb = t - (2 * n_stages - 1 - s)
-                return (tb >= 0) & (tb % 2 == 0) & (tb // 2 < Mi), \
-                    jnp.clip(tb // 2, 0, Mi - 1)
-
-            def tick(carry, t):
-                h_pend, cot_pend, act_buf, d_params, d_extras, d_xs = carry
-                is_f, jf = is_f_at(stage, t)
-                is_b, jb = is_b_at(stage, t)
-
-                def b_branch(op):
-                    h_pend, act_buf, d_params, d_extras, d_xs = op
-                    h_saved = jax.lax.dynamic_index_in_dim(
-                        act_buf, jb % W, axis=0, keepdims=False)
-                    dy_in = jnp.where(stage == n_stages - 1,
-                                      dy[jb] / tp_div, cot_pend)
-                    da = d_aux[jb].astype(jnp.float32) / tp_div
-                    _, vjp_fn = jax.vjp(stage_fn, params_local, h_saved,
-                                        extras_local)
-                    dp, dh, de = vjp_fn((dy_in, da.reshape(())))
-                    d_params = jax.tree.map(jnp.add, d_params, dp)
-                    d_extras = jax.tree.map(jnp.add, d_extras, de)
-                    upd = jax.lax.dynamic_update_slice(
-                        d_xs, dh[None].astype(d_xs.dtype),
-                        (jb,) + (0,) * dh.ndim)
-                    d_xs = jnp.where(stage == 0, upd, d_xs)
-                    return zeros_mb, dh, act_buf, d_params, d_extras, d_xs
-
-                def f_branch(op):
-                    h_pend, act_buf, d_params, d_extras, d_xs = op
-
-                    def do_f(opb):
-                        h_pend, act_buf = opb
-                        x_in = jnp.where(stage == 0, xs[jf], h_pend)
-                        h_out, _ = stage_fn(params_local, x_in, extras_local)
-                        act_buf = jax.lax.dynamic_update_slice(
-                            act_buf, x_in[None],
-                            (jf % W,) + (0,) * x_in.ndim)
-                        return h_out, act_buf
-
-                    h_out, act_buf = jax.lax.cond(
-                        is_f, do_f, lambda opb: (zeros_mb, opb[1]),
-                        (h_pend, act_buf))
-                    return h_out, zeros_mb, act_buf, d_params, d_extras, d_xs
-
-                out = jax.lax.cond(
-                    is_b, b_branch, f_branch,
-                    (h_pend, act_buf, d_params, d_extras, d_xs))
-                h_pay, cot_pay, act_buf, d_params, d_extras, d_xs = out
-                h_recv = jax.lax.ppermute(h_pay, axis, fwd_perm)
-                cot_recv = jax.lax.ppermute(cot_pay, axis, bwd_perm)
-                # latch: accept only freshly-produced neighbor values (idle
-                # ticks send zeros, and across the warmup/steady boundary a
-                # value is consumed several ticks after it was produced)
-                prev_f, _ = is_f_at((stage - 1) % n_stages, t)
-                next_b, _ = is_b_at((stage + 1) % n_stages, t)
-                h_pend = jnp.where(prev_f, h_recv, h_pend)
-                cot_pend = jnp.where(next_b, cot_recv, cot_pend)
-                return (h_pend, cot_pend, act_buf,
-                        d_params, d_extras, d_xs), None
-
-            carry0 = (zeros_mb, zeros_mb,
-                      jnp.zeros((W,) + mb_shape, xs.dtype),
-                      jax.tree.map(jnp.zeros_like, params_local),
-                      jax.tree.map(jnp.zeros_like, extras_local),
-                      jnp.zeros_like(xs))
-            (_, _, _, d_params, d_extras, d_xs), _ = jax.lax.scan(
-                tick, carry0, jnp.arange(total))
-            d_params = jax.tree.map(
-                lambda g, axes: jax.lax.psum(g, axes) if axes else g,
-                d_params, p_reduce)
-            d_extras = jax.tree.map(
-                lambda g: jax.lax.psum(g, e_reduce), d_extras)
-            # only stage 0 wrote d_xs; under TP its per-model-rank values
-            # are split cotangents — the psum also recombines those
-            d_xs = jax.lax.psum(
-                d_xs, (axis,) + ((tp_axis,) if tp_axis else ()))
-            return d_params, d_xs, d_extras
-
-        bwd_sm = _shard_map(
-            bwd_body, mesh,
-            in_specs=(specs.pspec, specs.x_spec, specs.espec,
-                      specs.x_spec, P()),
-            out_specs=(specs.pspec, specs.x_spec, specs.espec))
-
-        @jax.custom_vjp
-        def call(stage_params, x, extras):
-            return fwd_sm(stage_params, x, extras)
-
-        def call_fwd(stage_params, x, extras):
-            # residuals are the schedule *inputs* only — the backward
-            # regenerates stage activations just-in-time (<= P in flight)
-            return fwd_sm(stage_params, x, extras), (stage_params, x, extras)
-
-        def call_bwd(res, cots):
-            stage_params, x, extras = res
-            d_out, d_aux = cots
-            return bwd_sm(stage_params, x, extras, d_out, d_aux)
-
-        call.defvjp(call_fwd, call_bwd)
-        return call(stage_params, x, extras)
-
-
 # ---------------------------------------------------------------------------
-# table-driven schedules: interleaved 1F1B and zero-bubble
+# table-driven schedules: 1F1B, interleaved 1F1B and zero-bubble
 # ---------------------------------------------------------------------------
-# The closed-form tick arithmetic of OneFOneBSchedule does not extend to
-# interleaved virtual stages (per-rank op order depends on warmup depth AND
-# chunk rotation) or to zero-bubble's three sub-tick kinds, so these
-# schedules build an explicit host-side (tick, rank) -> (op, chunk, mb)
-# table with a greedy list scheduler and drive both the primal forward
-# scan and the custom_vjp combined recompute/backward scan from static
-# int32 arrays derived from that table.
+# Every schedule that recomputes its forward in the backward runs through
+# one executor (``_TableSchedule.apply``).  A schedule only supplies its
+# host-side (tick, rank) -> (op, chunk, mb) table: 1F1B's is closed-form,
+# the interleaved and zero-bubble ones come from greedy list schedulers
+# (their per-rank op order depends on warmup depth and chunk rotation, or
+# on three sub-tick kinds).  Static int32 arrays derived from the table
+# drive both the primal forward scan and the custom_vjp combined
+# recompute/backward scan.
 
 _OP_CODES = {"idle": 0, "F": 1, "B": 2, "W": 3}
 
@@ -850,13 +649,14 @@ def _sched_arrays(table, P_, M, v, df, db):
 
 
 class _TableSchedule(PipelineSchedule):
-    """Shared executor for the table-driven schedules (interleaved 1F1B,
-    zero-bubble).  Subclasses provide the full fwd+bwd table; execution
-    follows the 1F1B custom_vjp pattern — the primal stores only the
-    schedule inputs, the backward replays microbatch forwards
-    just-in-time — generalized to per-chunk ring buffers, a chunked view
-    of the rank's layer slice, and (zb) a deferred parameter-gradient
-    stash written at the dgrad sub-tick and drained at the wgrad one."""
+    """The executor of every recomputing schedule (1F1B, interleaved 1F1B,
+    zero-bubble).  Subclasses provide the full fwd+bwd table.  The
+    ``jax.custom_vjp`` primal stores only the schedule inputs; the
+    backward replays microbatch forwards just-in-time, holding per-chunk
+    ring buffers sized from the table (``_ring_depths``), the rank's
+    layer slice cut into its v chunks, and (zb) a deferred
+    parameter-gradient stash written at the dgrad sub-tick and drained at
+    the wgrad one."""
 
     v: int = 1
     has_wgrad: bool = False
@@ -890,12 +690,12 @@ class _TableSchedule(PipelineSchedule):
             raise ValueError(
                 f"{L} stacked layers do not split into pipe={n_stages} x "
                 f"v={v} virtual-stage chunks ({self.name})")
+        nl = L // (n_stages * v)            # layers in one chunk
         if v > 1:
             # re-chunk the stack: rank r's contiguous pipe shard must hold
             # the v non-contiguous slices of virtual stages c*P + r.
             # jnp.take is differentiable and sits outside the custom_vjp,
             # so its transpose un-permutes the param cotangents for free.
-            nl = L // (n_stages * v)
             perm = np.array([(c * n_stages + r) * nl + i
                              for r in range(n_stages)
                              for c in range(v)
@@ -905,15 +705,16 @@ class _TableSchedule(PipelineSchedule):
         specs = _resolve_specs(stage_params, x, mesh, axis, extras,
                                batch_axes, param_specs, seq_axis)
 
-        def chunked(tree):
+        def chunk(tree, c):
+            # chunk c of the rank's layer slice: rows [c*nl, (c+1)*nl)
             return jax.tree.map(
-                lambda a: a.reshape((v, a.shape[0] // v) + a.shape[1:]),
-                tree)
+                lambda a: jax.lax.dynamic_slice_in_dim(a, c * nl, nl), tree)
 
-        def pick(tree, idx):
+        def add_to_chunk(tree, g, c):
             return jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, idx, 0, keepdims=False), tree)
+                lambda a, ga: jax.lax.dynamic_update_slice_in_dim(
+                    a, jax.lax.dynamic_slice_in_dim(a, c * nl, nl) + ga,
+                    c * nl, 0), tree, g)
 
         def ring_read(buf, c, slot):
             return jax.lax.dynamic_index_in_dim(
@@ -930,7 +731,6 @@ class _TableSchedule(PipelineSchedule):
         def fwd_body(params_local, xs, extras_local):
             stage = jax.lax.axis_index(axis)
             mb_shape = xs.shape[1:]
-            pv = chunked(params_local)
             arrs = _sched_arrays(fwd_table, n_stages, M, v, f_df, 1)
 
             def tick(carry, tarr):
@@ -944,7 +744,8 @@ class _TableSchedule(PipelineSchedule):
                 h_in = jnp.where(first, xs[j], ring_read(pend_h, c, slot))
                 a_in = jnp.where(first, jnp.zeros((1,), jnp.float32),
                                  ring_read(pend_a, c, slot))
-                h_out, a_stage = stage_fn(pick(pv, c), h_in, extras_local)
+                h_out, a_stage = stage_fn(chunk(params_local, c), h_in,
+                                          extras_local)
                 a_out = a_in + a_stage.astype(jnp.float32).reshape((1,))
                 emit = (op == 1) & last
                 outputs = jax.lax.cond(
@@ -981,51 +782,62 @@ class _TableSchedule(PipelineSchedule):
             return outputs, aux_mb
 
         tok_axes = _token_axes(specs)
-        # split-cotangent Megatron-TP convention — see OneFOneBSchedule
+        # Megatron-TP cotangent convention inside the manual loop: the
+        # stage body contains raw psums, so a replicated value's physical
+        # cotangents must SUM across model ranks to the logical one (the
+        # "split" convention — see layers.tp_reduce_out).  Injected
+        # cotangents (dy, d_aux) are therefore divided by tp, and the
+        # final reductions psum back over the model axis.
         tp_div = mesh.shape[tp_axis] if tp_axis else 1
         grad_axes = tok_axes + (
             (tp_axis,) if tp_axis and tp_axis not in tok_axes else ())
+        # per-leaf gradient reduction: sum over the axes this leaf is
+        # replicated across but whose contributions are distinct (token
+        # shards; split model-cotangents under TP).  A leaf already
+        # sharded over 'expert'/'model' owns its slice's cotangent.
         p_reduce = jax.tree.map(
             lambda sp: tuple(a for a in grad_axes
                              if a not in _spec_axes(sp)),
             specs.pspec, is_leaf=lambda s: isinstance(s, P))
+        # extras feed every stage and every token/head shard
         e_reduce = (axis,) + grad_axes
 
         def bwd_body(params_local, xs, extras_local, dy, d_aux):
             stage = jax.lax.axis_index(axis)
             mb_shape = xs.shape[1:]
-            pv = chunked(params_local)
             arrs = _sched_arrays(full_table, n_stages, M, v, df, db)
             zeros_mb = jnp.zeros(mb_shape, xs.dtype)
 
             def tick(carry, tarr):
                 (pend_h, pend_c, act_buf, d_params, d_extras, d_xs,
                  dp_stash) = carry
+                op = tarr["op"][stage]
                 c = tarr["c"][stage]
                 j = tarr["j"][stage]
                 first = tarr["sv0"][stage].astype(bool)
                 last = tarr["svl"][stage].astype(bool)
-                cp = pick(pv, c)
+                cp = chunk(params_local, c)
 
-                def idle_br(op_in):
-                    (pend_h, pend_c, act_buf, d_params, d_extras, d_xs,
-                     dp_stash) = op_in
-                    return (zeros_mb, zeros_mb, act_buf, d_params,
-                            d_extras, d_xs, dp_stash)
-
-                def f_br(op_in):
-                    (pend_h, pend_c, act_buf, d_params, d_extras, d_xs,
-                     dp_stash) = op_in
+                def fwd_op(bufs):
+                    pend_h, act_buf = bufs
                     x_in = jnp.where(first, xs[j],
                                      ring_read(pend_h, c, jnp.mod(j, df)))
                     h_out, _ = stage_fn(cp, x_in, extras_local)
-                    act_buf = ring_write(act_buf, x_in, c, jnp.mod(j, da))
-                    return (h_out, zeros_mb, act_buf, d_params, d_extras,
-                            d_xs, dp_stash)
+                    return h_out, ring_write(act_buf, x_in, c,
+                                             jnp.mod(j, da))
 
-                def b_br(op_in):
-                    (pend_h, pend_c, act_buf, d_params, d_extras, d_xs,
-                     dp_stash) = op_in
+                def fwd_or_idle(grads):
+                    # A forward touches only the inbound activations and
+                    # the stored inputs, so it runs in a cond of its own:
+                    # the gradient state passed through the branch that
+                    # runs the stage would be copied leaf by leaf each tick.
+                    f_pay, act_buf_ = jax.lax.cond(
+                        op == _OP_CODES["F"], fwd_op,
+                        lambda bufs: (zeros_mb, bufs[1]), (pend_h, act_buf))
+                    return (f_pay, zeros_mb, act_buf_) + grads
+
+                def b_op(grads):
+                    d_params, d_extras, d_xs, dp_stash = grads
                     h_saved = ring_read(act_buf, c, jnp.mod(j, da))
                     dy_in = jnp.where(last, dy[j] / tp_div,
                                       ring_read(pend_c, c, jnp.mod(j, db)))
@@ -1041,42 +853,32 @@ class _TableSchedule(PipelineSchedule):
                                 (jnp.mod(j, dw),) + (0,) * g.ndim),
                             dp_stash, dpc)
                     else:
-                        d_params = jax.tree.map(
-                            lambda A, g: jax.lax.dynamic_update_slice(
-                                A, (jax.lax.dynamic_index_in_dim(
-                                    A, c, 0, keepdims=False) + g)[None],
-                                (c,) + (0,) * g.ndim),
-                            d_params, dpc)
+                        d_params = add_to_chunk(d_params, dpc, c)
                     d_extras = jax.tree.map(jnp.add, d_extras, de)
                     upd = jax.lax.dynamic_update_slice(
                         d_xs, dh[None].astype(d_xs.dtype),
                         (j,) + (0,) * dh.ndim)
                     d_xs = jnp.where(first, upd, d_xs)
-                    return (zeros_mb, dh, act_buf, d_params, d_extras,
+                    return (zeros_mb, dh, act_buf, d_params, d_extras, d_xs,
+                            dp_stash)
+
+                def w_op(grads):
+                    d_params, d_extras, d_xs, dp_stash = grads
+                    g = jax.tree.map(
+                        lambda s: jax.lax.dynamic_index_in_dim(
+                            s, jnp.mod(j, dw), 0, keepdims=False), dp_stash)
+                    d_params = add_to_chunk(d_params, g, c)
+                    return (zeros_mb, zeros_mb, act_buf, d_params, d_extras,
                             d_xs, dp_stash)
 
-                def w_br(op_in):
-                    (pend_h, pend_c, act_buf, d_params, d_extras, d_xs,
-                     dp_stash) = op_in
-                    g = pick(dp_stash, jnp.mod(j, dw))
-                    d_params = jax.tree.map(
-                        lambda A, gg: jax.lax.dynamic_update_slice(
-                            A, (jax.lax.dynamic_index_in_dim(
-                                A, c, 0, keepdims=False) + gg)[None],
-                            (c,) + (0,) * gg.ndim),
-                        d_params, g)
-                    return (zeros_mb, zeros_mb, act_buf, d_params,
-                            d_extras, d_xs, dp_stash)
-
-                branches = [idle_br, f_br, b_br]
+                # branch 0 serves idle and F ticks, 1 B, 2 W
+                branches = [fwd_or_idle, b_op]
                 if self.has_wgrad:
-                    branches.append(w_br)
-                out = jax.lax.switch(
-                    tarr["op"][stage], branches,
-                    (pend_h, pend_c, act_buf, d_params, d_extras, d_xs,
-                     dp_stash))
+                    branches.append(w_op)
                 (f_pay, b_pay, act_buf, d_params, d_extras, d_xs,
-                 dp_stash) = out
+                 dp_stash) = jax.lax.switch(
+                    jnp.maximum(op - _OP_CODES["F"], 0), branches,
+                    (d_params, d_extras, d_xs, dp_stash))
                 h_recv = jax.lax.ppermute(f_pay, axis, fwd_perm)
                 c_recv = jax.lax.ppermute(b_pay, axis, bwd_perm)
                 pend_h = jax.lax.cond(
@@ -1093,24 +895,24 @@ class _TableSchedule(PipelineSchedule):
                         d_xs, dp_stash), None
 
             dp_stash0 = (jax.tree.map(
-                lambda a: jnp.zeros((dw,) + a.shape[1:], a.dtype), pv)
-                if self.has_wgrad else None)
+                lambda a: jnp.zeros((dw, nl) + a.shape[1:], a.dtype),
+                params_local) if self.has_wgrad else None)
             carry0 = (jnp.zeros((v, df) + mb_shape, xs.dtype),
                       jnp.zeros((v, db) + mb_shape, xs.dtype),
                       jnp.zeros((v, da) + mb_shape, xs.dtype),
-                      jax.tree.map(jnp.zeros_like, pv),
+                      jax.tree.map(jnp.zeros_like, params_local),
                       jax.tree.map(jnp.zeros_like, extras_local),
                       jnp.zeros_like(xs),
                       dp_stash0)
             (_, _, _, d_params, d_extras, d_xs, _), _ = jax.lax.scan(
                 tick, carry0, arrs)
             d_params = jax.tree.map(
-                lambda A, a: A.reshape(a.shape), d_params, params_local)
-            d_params = jax.tree.map(
                 lambda g, axes: jax.lax.psum(g, axes) if axes else g,
                 d_params, p_reduce)
             d_extras = jax.tree.map(
                 lambda g: jax.lax.psum(g, e_reduce), d_extras)
+            # only stage 0 wrote d_xs; under TP its per-model-rank values
+            # are split cotangents — the psum also recombines those
             d_xs = jax.lax.psum(
                 d_xs, (axis,) + ((tp_axis,) if tp_axis else ()))
             return d_params, d_xs, d_extras
@@ -1139,6 +941,31 @@ class _TableSchedule(PipelineSchedule):
 
         call.defvjp(call_fwd, call_bwd)
         return call(stage_params, x, extras)
+
+
+class OneFOneBSchedule(_TableSchedule):
+    """1F1B (PipeDream-flush): stage s runs P - s warmup forwards, then
+    alternates one-forward-one-backward, then drains.  Per-stage in-flight
+    activations <= P instead of M.
+
+    The table is closed-form: stage s forwards microbatch j at tick
+    ``s + j`` during warmup (j < P - s) and ``2j + s`` in steady state; it
+    backwards j at ``2j + 2P - 1 - s``.  Run by the shared executor, it
+    holds min(M, P) stage inputs and one inbound value per ring."""
+
+    name = "1f1b"
+
+    def _full_table(self, n_stages, n_microbatches):
+        P_, M = n_stages, n_microbatches
+        if M < P_:
+            raise ValueError(f"1f1b needs microbatches >= stages "
+                             f"(got M={M} < P={P_})")
+        table = [[("idle", 0, 0)] * P_ for _ in range(2 * (M + P_ - 1))]
+        for s in range(P_):
+            for j in range(M):
+                table[s + j if j < P_ - s else 2 * j + s][s] = ("F", 0, j)
+                table[2 * j + 2 * P_ - 1 - s][s] = ("B", 0, j)
+        return table
 
 
 class InterleavedOneFOneBSchedule(_TableSchedule):
@@ -1307,63 +1134,3 @@ def make_pipelined_block_fn(cfg, rt):
         return h, aux
 
     return stage_fn
-
-
-def measure_bubble_fraction(step_for_m: Callable[[int], Callable[[], object]],
-                            n_stages: int, microbatches: int,
-                            m2: Optional[int] = None,
-                            n_iter: int = 3, sched: str = "gpipe") -> dict:
-    """Empirically estimate the pipeline bubble from wall time.
-
-    ``step_for_m(M)`` returns a zero-arg compiled callable running the
-    pipelined step with M microbatches at *fixed microbatch size* (total
-    batch grows with M), so t(M) = t_tick * (M + P - 1) + overhead is
-    linear in M.  A two-point fit recovers t_tick, and
-
-        bubble_measured = (P - 1) * t_tick / t(M)
-
-    which equals (P-1)/(M+P-1) up to the constant overhead term — the
-    executable counterpart of ``bubble_fraction`` / the cost model's
-    per-schedule bubble charge.
-
-    Schedule generalization: d(total ticks)/dM is v for interleaved
-    (t(M) = t_tick*(vM + P - 1)) and 3 for zb (t(M) = t_tick*(3M+2P-2)),
-    so the fitted slope is divided by that coefficient before applying
-    the schedule's drain numerator ((P-1), or 2(P-1) for zb).  The
-    record carries ``virtual_stages`` so downstream artifacts can
-    validate the interleaved probe against (P-1)/(vM+P-1).
-
-    On a noisy host the two-point fit can come out non-increasing
-    (t(2M) <= t(M)); that is *not* a zero bubble, it is a failed fit —
-    the record flags it as ``fit_unreliable`` so downstream consumers
-    (dryrun artifacts, BENCH_pipeline.json, the tier-1 probe test) can
-    retry or discard instead of trusting a fabricated 0.0.
-    """
-    m1 = microbatches
-    m2 = m2 or 2 * m1
-
-    def timed(fn):
-        fn()                                   # compile / warm up
-        best = float("inf")
-        for _ in range(n_iter):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    t1 = timed(step_for_m(m1))
-    t2 = timed(step_for_m(m2))
-    unreliable = t2 <= t1 or t1 <= 0
-    family, v = parse_schedule(sched)
-    ticks_per_m = 3 if family == "zb" else v
-    drain = 2 * (n_stages - 1) if family == "zb" else n_stages - 1
-    t_tick = max((t2 - t1) / (m2 - m1), 0.0) / ticks_per_m
-    measured = drain * t_tick / t1 if t1 > 0 else 0.0
-    return {
-        "pp": n_stages, "microbatches": m1, "sched": sched,
-        "virtual_stages": v,
-        "t_step_s": t1, "t_step_2m_s": t2, "t_tick_s": t_tick,
-        "bubble_predicted": bubble_fraction(n_stages, m1, sched),
-        "bubble_measured": measured,
-        "fit_unreliable": bool(unreliable),
-    }

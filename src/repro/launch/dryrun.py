@@ -10,9 +10,8 @@ def _force_fake_devices(argv):
     Pod meshes need 512 fake devices (override=True: the appended flag
     wins over any smaller env default); '--topology host' keeps a small
     live mesh (8, or whatever the environment already set) so compiled
-    steps can also be *executed* (e.g. the --measure_bubble pipeline
-    probe).  CLI-only: importing this module as a library leaves the
-    caller's device count alone.
+    steps can also be *executed*.  CLI-only: importing this module as a
+    library leaves the caller's device count alone.
     """
     topo = ""
     for i, a in enumerate(argv):
@@ -40,8 +39,6 @@ Usage:
   python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k
   python -m repro.launch.dryrun --arch all --shape all [--multi_pod]
   python -m repro.launch.dryrun ... --out results/dryrun
-  python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k \
-      --topology host --reduced --strategy fsdp_pp2_mb8 --measure_bubble
 """
 import argparse
 import dataclasses
@@ -203,8 +200,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             rt_overrides=None, donate: bool = False,
             seq_parallel: bool = True, grad_accum: int = 1,
             strategy: str = "", topology: str = "",
-            use_reduced: bool = False, measure_bubble: bool = False,
-            telemetry=None):
+            use_reduced: bool = False, telemetry=None):
     from repro import telemetry as tel
     telemetry = telemetry if telemetry is not None else tel.NULL
     mesh_name, label = run_label(arch, shape_name, multi_pod, strategy, tag,
@@ -304,10 +300,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             stats1 = dispatch_stats_snapshot()
             rec["moe_dispatch"] = {k: stats1[k] - stats0[k] for k in stats1}
         if strat.pp > 1:
-            # pipeline section: the analytic per-schedule bubble and
-            # in-flight activation count, plus (on a live host mesh with
-            # --measure_bubble) the executed bubble, so the cost model's
-            # schedule terms are validated, not assumed
+            # pipeline section: the analytic per-schedule bubble,
+            # in-flight activation count and sub-tick census
             from repro.core.pipeline import (bubble_fraction,
                                              inflight_microbatches,
                                              op_tick_counts,
@@ -326,19 +320,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
                 "op_tick_counts": op_tick_counts(
                     strat.sched, strat.pp, strat.microbatches),
             }
-            # the probe only means something on a live host mesh: on a
-            # pod topology the 512 CPU-emulated fake devices would
-            # "measure" emulation overhead, not the schedule
-            topo_obj = _topology(topology, multi_pod)
-            if measure_bubble and topology == "host" and \
-                    topo_obj.n_devices <= len(jax.devices()):
-                from repro.configs import reduced
-                from repro.perf.pipeline_probe import measure_bubble as _probe
-                # layer count must split into pp x v virtual-stage chunks
-                chunk = strat.pp * virtual_stages(strat.sched)
-                n_l = -(-max(4, 2 * strat.pp) // chunk) * chunk
-                probe_cfg = reduced(get_config(arch), n_layers=n_l)
-                rec["pipeline"].update(_probe(probe_cfg, strat, topo_obj))
         print(f"[dryrun] {label}: OK  compile {t_compile:.0f}s  "
               f"flops {rec['flops_compiled_analytic']:.3e}  "
               f"coll {rec['collective_bytes_total']:.3e}B  "
@@ -366,14 +347,9 @@ def main():
     ap.add_argument("--both_meshes", action="store_true")
     ap.add_argument("--topology", default="",
                     help="'' = pod/multipod (512 fake devices); 'host' = "
-                         "small live mesh (compiled steps can execute, "
-                         "e.g. --measure_bubble)")
+                         "small live mesh (compiled steps can execute)")
     ap.add_argument("--reduced", action="store_true",
                     help="smoke-scale variant of each arch")
-    ap.add_argument("--measure_bubble", action="store_true",
-                    help="for pp>1 strategies on a live topology, execute "
-                         "the GPipe schedule and record the measured "
-                         "bubble fraction next to the prediction")
     ap.add_argument("--strategy", default="",
                     help="'' = legacy pod layout (model axis 16), 'auto' = "
                          "planner, else a spec string like hsdp_tp4 / "
@@ -451,7 +427,7 @@ def main():
                               args.attn, args.tag, rt_overrides, args.donate,
                               not args.no_sp, args.grad_accum, args.strategy,
                               args.topology, args.reduced,
-                              args.measure_bubble, telemetry=recorder)
+                              telemetry=recorder)
                 n_fail += rec["status"] == "error"
     recorder.close()
     if args.trace:

@@ -421,9 +421,7 @@ def _head(cfg: ModelConfig, params, h, rt: Runtime):
 
 
 def pipeline_stage_runtime(rt: Runtime, rows: int) -> Runtime:
-    """The stage-body Runtime for a pipeline microbatch of ``rows`` rows —
-    the single recipe for every ``pipeline_apply`` caller (the forward
-    path below AND ``perf/pipeline_probe.py``), so the two cannot drift.
+    """The stage-body Runtime for a pipeline microbatch of ``rows`` rows.
 
     The stage body runs inside a fully-manual shard_map: named sharding
     constraints and per-block FSDP gathers are meaningless there; MoE
@@ -464,9 +462,7 @@ def pipeline_stage_runtime(rt: Runtime, rows: int) -> Runtime:
 def pipeline_stage_param_specs(rt: Runtime, stage_params):
     """PartitionSpecs for a stage-param pytree via the plan's
     ``pipeline_param_spec_fn`` (stack dim over 'pipe' + inner
-    model/expert sharding); None when the runtime carries no spec fn.
-    Shared by the forward path and the bubble probe so both lower the
-    same physical program."""
+    model/expert sharding); None when the runtime carries no spec fn."""
     if rt.pipeline_param_spec_fn is None:
         return None
     return jax.tree_util.tree_map_with_path(

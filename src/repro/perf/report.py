@@ -93,9 +93,9 @@ def _benchmark_summaries() -> str:
 
 def _pipeline_sweep() -> str:
     """§Schedule-frontier table from BENCH_pipeline.json: the extended
-    pp x {gpipe,1f1b,1f1b_i<v>,zb} x overlap sweep with per-schedule
-    bubble (predicted + measured fit) and peak memory (cost model +
-    compiled-executable memory analysis)."""
+    pp x {gpipe,1f1b,1f1b_i<v>,zb} x overlap sweep with the analytic
+    per-schedule bubble and peak memory (cost model + compiled-executable
+    memory analysis)."""
     path = results_path("benchmarks", "BENCH_pipeline.json")
     if not os.path.exists(path):
         return "_(run `python benchmarks/run.py --pp-sweep` first)_\n"
@@ -114,22 +114,19 @@ def _pipeline_sweep() -> str:
            "bubble fraction (hardware-free) and the peak-memory pair — "
            "predicted (cost model in-flight term) next to measured "
            "(compiled executable temp bytes).\n",
-           "| spec | sched | v | ovl | bubble pred | bubble meas | "
+           "| spec | sched | v | ovl | bubble pred | "
            "mem pred MiB | mem meas MiB |",
-           "|---|---|---|---|---|---|---|---|"]
+           "|---|---|---|---|---|---|---|"]
     for r in bench.get("rows", []):
-        flag = "!" if r.get("fit_unreliable") else ""
         out.append(
             f"| {r['spec']} | {r.get('sched', '—')} "
             f"| {r.get('virtual_stages', 1)} "
             f"| {'on' if r.get('overlap') else 'off'} "
             f"| {_frac(r.get('bubble_predicted'))} "
-            f"| {_frac(r.get('bubble_measured'))}{flag} "
             f"| {_mib(r.get('predicted_peak_memory_bytes'))} "
             f"| {_mib(r.get('measured_temp_bytes'))} |")
-    out.append("\n`!` marks a `fit_unreliable` bubble fit (non-increasing "
-               "two-point measurement on a noisy host); `—` means the "
-               "backend reported no executable memory analysis.\n")
+    out.append("\n`—` means the backend reported no executable memory "
+               "analysis.\n")
     return "\n".join(out) + "\n"
 
 

